@@ -11,19 +11,19 @@
 #   staging, checkpoint gathers); tcmalloc keeps them off the serving
 #   hot path.  The preload is skipped when the library is absent, so
 #   the script is safe to source on minimal containers.
-# * XLA_FLAGS -- one host-platform device (the engine shards lanes, not
-#   processes).  On TPU builds additionally set
-#   "--xla_step_marker_location=1" (step markers at the outer while
+# * XLA_FLAGS -- left as the caller set it.  On TPU, profiling runs may
+#   add "--xla_step_marker_location=1" (step markers at the outer while
 #   loop, so profile traces cut at dispatch boundaries, matching the
-#   span tracer); CPU-only XLA builds reject the flag, so it stays off
-#   by default.
+#   span tracer); CPU-only XLA builds reject the flag.
 # * TF_CPP_MIN_LOG_LEVEL=4 -- silence the TF/XLA banner spam that
 #   otherwise drowns the serve launcher's throughput lines.
-# * JAX_COMPILATION_CACHE_DIR -- persistent XLA compilation cache: a
-#   relaunch (same config/buckets) reuses compiled prefill/decode
-#   executables instead of re-tracing.  The serve launcher and
-#   bench-smoke report their steady-state compile counters so a cold
-#   cache is visible (see BENCH_decode.json "warm_start").
+# * Persistent XLA compilation cache: a relaunch (same config/buckets)
+#   reuses compiled prefill/decode executables instead of re-tracing.
+#   The launchers keep it in JAX_COMPILATION_CACHE_DIR when that is set
+#   and in <repo>/.jax_cache otherwise (repro.launch.compile_cache).  The
+#   serve launcher and bench-smoke report their steady-state compile
+#   counters so a cold cache is visible (see BENCH_decode.json
+#   "warm_start").
 
 _TCMALLOC=/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4
 if [ -e "${_TCMALLOC}" ]; then
@@ -33,10 +33,3 @@ fi
 unset _TCMALLOC
 
 export TF_CPP_MIN_LOG_LEVEL=4
-export XLA_FLAGS="--xla_force_host_platform_device_count=1"
-# export XLA_FLAGS="--xla_step_marker_location=1 ${XLA_FLAGS}"  # TPU builds
-
-# Persistent compilation cache (override the location before sourcing
-# to share one cache across checkouts).
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${HOME}/.cache/repro-jax}"
-mkdir -p "${JAX_COMPILATION_CACHE_DIR}"

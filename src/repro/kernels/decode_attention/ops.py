@@ -1,9 +1,15 @@
-"""Jitted wrapper for decode attention, dense + quantized-KV.
+"""Decode attention as the model calls it.
 
-``length_aware=True`` (the default for the Pallas path) routes to the
-scalar-prefetch kernels whose HBM reads scale with the live cache
-length; ``length_aware=False`` keeps the masked full-``max_len`` stream
-as the parity reference.  The jnp oracle is unaffected by the flag.
+The paged ops pick their kernel by platform when they are lowered
+(``jax.lax.platform_dependent``): the block-table Pallas kernel on TPU,
+the jnp oracle everywhere else.  No flag selects between them, so the
+CPU tests run the oracle and the chip runs the kernel; the Pallas
+kernels themselves are tested against the oracles in interpret mode.
+
+The dense-cache ops stay on the jnp oracle on every platform.  The
+fixed-lane dense cache is the parity reference, not the served layout,
+and Whisper's cross-attention reads a 1500-key encoder cache, which is
+not a multiple of the dense kernel's key block.
 """
 
 from __future__ import annotations
@@ -13,73 +19,45 @@ import functools
 import jax
 
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_lengthaware_pallas, decode_attention_paged_pallas,
-    decode_attention_paged_q8_pallas, decode_attention_pallas,
-    decode_attention_q8_lengthaware_pallas, decode_attention_q8_pallas)
+    decode_attention_paged_pallas, decode_attention_paged_q8_pallas)
 from repro.kernels.decode_attention.ref import (
     decode_attention_paged_q8_ref, decode_attention_paged_ref,
     decode_attention_q8_ref, decode_attention_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret", "bk",
-                                             "length_aware"))
-def decode_attention(q, k, v, kv_lengths, *, use_pallas: bool = False,
-                     interpret: bool = False, bk: int = 512,
-                     length_aware: bool = True):
-    if use_pallas:
-        if length_aware:
-            return decode_attention_lengthaware_pallas(
-                q, k, v, kv_lengths, bk=bk, interpret=interpret)
-        return decode_attention_pallas(q, k, v, kv_lengths, bk=bk,
-                                       interpret=interpret)
+@jax.jit
+def decode_attention(q, k, v, kv_lengths):
     return decode_attention_ref(q, k, v, kv_lengths)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret", "bk",
-                                             "qblock", "length_aware"))
+@functools.partial(jax.jit, static_argnames=("qblock",))
 def decode_attention_q8(q, k_q, k_scale, v_q, v_scale, kv_lengths, *,
-                        use_pallas: bool = False, interpret: bool = False,
-                        bk: int = 512, qblock: int = 32,
-                        length_aware: bool = True):
-    if use_pallas:
-        if length_aware:
-            return decode_attention_q8_lengthaware_pallas(
-                q, k_q, k_scale, v_q, v_scale, kv_lengths, bk=bk,
-                qblock=qblock, interpret=interpret)
-        return decode_attention_q8_pallas(q, k_q, k_scale, v_q, v_scale,
-                                          kv_lengths, bk=bk, qblock=qblock,
-                                          interpret=interpret)
+                        qblock: int = 32):
     return decode_attention_q8_ref(q, k_q, k_scale, v_q, v_scale, kv_lengths,
                                    qblock=qblock)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_lengths, *,
-                           use_pallas: bool = False,
-                           interpret: bool = False):
+@jax.jit
+def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_lengths):
     """Block-table decode attention over a global page pool.
 
-    The Pallas path is always length-aware (the table walk is clamped to
-    the last live page); the jnp oracle gathers the pages first.
+    On TPU the Pallas kernel walks the table, clamped to the last live
+    page; elsewhere the jnp oracle gathers the pages first.
     """
-    if use_pallas:
-        return decode_attention_paged_pallas(q, k_pages, v_pages,
-                                             block_tables, kv_lengths,
-                                             interpret=interpret)
-    return decode_attention_paged_ref(q, k_pages, v_pages, block_tables,
-                                      kv_lengths)
+    return jax.lax.platform_dependent(
+        q, k_pages, v_pages, block_tables, kv_lengths,
+        tpu=decode_attention_paged_pallas,
+        default=decode_attention_paged_ref)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("use_pallas", "interpret", "qblock"))
+@functools.partial(jax.jit, static_argnames=("qblock",))
 def decode_attention_paged_q8(q, k_pages, k_scale_pages, v_pages,
                               v_scale_pages, block_tables, kv_lengths, *,
-                              use_pallas: bool = False,
-                              interpret: bool = False, qblock: int = 32):
-    if use_pallas:
-        return decode_attention_paged_q8_pallas(
-            q, k_pages, k_scale_pages, v_pages, v_scale_pages,
-            block_tables, kv_lengths, qblock=qblock, interpret=interpret)
-    return decode_attention_paged_q8_ref(
+                              qblock: int = 32):
+    return jax.lax.platform_dependent(
         q, k_pages, k_scale_pages, v_pages, v_scale_pages, block_tables,
-        kv_lengths, qblock=qblock)
+        kv_lengths,
+        tpu=functools.partial(decode_attention_paged_q8_pallas,
+                              qblock=qblock),
+        default=functools.partial(decode_attention_paged_q8_ref,
+                                  qblock=qblock))

@@ -1,9 +1,11 @@
-"""Jitted wrapper for flash attention with three lowering paths.
+"""Jitted wrapper for full-sequence attention.
 
-* ``use_pallas=True``  -- the Pallas TPU kernel (tests run interpret=True)
-* default              -- blockwise jnp scan: O(Sq x block) memory, the
-  path the distributed dry-run lowers (GSPMD-shardable, CPU-compilable)
-* ``naive=True``       -- (Sq x Sk) reference, used only as the oracle in
+* default        -- blockwise jnp scan: O(Sq x block) memory, the path
+  prefill, training and the distributed dry-run lower on every platform
+  (GSPMD-shardable, CPU-compilable).  The Pallas flash kernel
+  (``flash_attention_pallas``) defines no VJP, so the training forward
+  cannot use it, and prefill shares this forward.
+* ``naive=True`` -- (Sq x Sk) reference, used only as the oracle in
   kernel tests.
 """
 
@@ -14,20 +16,13 @@ import functools
 import jax
 
 from repro.kernels.flash_attention.blockwise import blockwise_attention
-from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "use_pallas",
-                                             "interpret", "bq", "bk",
-                                             "naive", "block"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "naive",
+                                             "block"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    use_pallas: bool = False, interpret: bool = False,
-                    naive: bool = False, block: int = 512,
-                    bq: int = 128, bk: int = 128):
-    if use_pallas:
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      bq=bq, bk=bk, interpret=interpret)
+                    naive: bool = False, block: int = 512):
     if naive:
         return attention_ref(q, k, v, causal=causal, window=window)
     return blockwise_attention(q, k, v, causal=causal, window=window,

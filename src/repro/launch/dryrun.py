@@ -1,15 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
-# REPRO_SCAN_UNROLL is toggled per-compile inside run_cell: the scanned
-# build gives the production memory analysis (remat-aware liveness), the
-# unrolled build gives per-layer-accurate FLOPs / collective counts.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this script
 
-1. builds the production mesh (16x16 single-pod / 2x16x16 multi-pod),
+1. builds the production mesh (16x16 single-pod / 2x16x16 multi-pod)
+   over host CPU devices,
 2. constructs ShapeDtypeStruct stand-ins for params / optimizer state /
    batch / cache (zero allocation, ``jax.eval_shape``),
 3. ``jax.jit(step, in_shardings, out_shardings).lower(...).compile()``,
@@ -18,34 +12,48 @@ For each cell this script
 5. appends a JSON line consumed by ``repro.core.roofline`` and
    EXPERIMENTS.md.
 
-The XLA_FLAGS line above MUST precede any jax import: device count locks
-at first backend initialization.
+The dry-run is a compile proof on host devices and never takes an
+accelerator: :func:`host_devices` pins the process to the CPU backend
+with ``REPRO_DRYRUN_DEVICES`` (default 512) devices.  It must run before
+anything else in the process initializes a JAX backend.
+REPRO_SCAN_UNROLL is toggled per-compile inside run_cell: the scanned
+build gives the production memory analysis (remat-aware liveness), the
+unrolled build gives per-layer-accurate FLOPs / collective counts.
 """
 
-import argparse    # noqa: E402
-import functools   # noqa: E402
-import json        # noqa: E402
-import time        # noqa: E402
-import traceback   # noqa: E402
+import argparse
+import functools
+import json
+import os
+import time
+import traceback
 
-import jax                       # noqa: E402
-import jax.numpy as jnp          # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import (ARCH_IDS, SHAPES, all_cells, get_config,  # noqa: E402
+from repro.configs import (ARCH_IDS, SHAPES, all_cells, get_config,
                            shape_applicable)
-from repro.core.hlo_analysis import collective_bytes   # noqa: E402
-from repro.core.roofline import analyze                # noqa: E402
-from repro.launch.mesh import make_production_mesh     # noqa: E402
-from repro.models import build_model                   # noqa: E402
-from repro.models.common import ModelConfig            # noqa: E402
-from repro.models.transformer import (init_cache, lm_decode_step,  # noqa: E402
+from repro.core.hlo_analysis import collective_bytes
+from repro.launch.mesh import make_production_mesh
+from repro.models import build_model
+from repro.models.common import ModelConfig
+from repro.models.transformer import (init_cache, lm_decode_step,
                                       lm_prefill_batched)
-from repro.models.whisper import (decode_forward, encode,  # noqa: E402
+from repro.models.whisper import (decode_forward, encode,
                                   init_whisper_cache, whisper_decode_step)
-from repro.parallel.sharding import (batch_shardings, cache_shardings,  # noqa: E402
-                                     param_shardings, replicated, use_mesh)
-from repro.train import TrainConfig, init_train_state, make_train_step  # noqa: E402
+from repro.parallel.sharding import (batch_shardings, cache_shardings,
+                                     param_shardings, use_mesh)
+from repro.train import TrainConfig, init_train_state, make_train_step
+
+
+def host_devices():
+    """Pin this process to the CPU backend with ``REPRO_DRYRUN_DEVICES``
+    host devices and return them."""
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices",
+                      int(os.environ.get("REPRO_DRYRUN_DEVICES", "512")))
+    return jax.devices("cpu")
 
 
 def _sds(shape, dtype):
@@ -301,7 +309,8 @@ def _compile_once(cfg, mesh, shape_name, unroll: bool,
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              verbose: bool = True, cost_pass: bool = True) -> dict:
     cfg = get_config(arch)
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = make_production_mesh(multi_pod=multi_pod,
+                                devices=host_devices())
     chips = mesh.size
     kind = SHAPES[shape_name]["kind"]
 

@@ -1,32 +1,46 @@
-"""Production mesh construction (single-pod 16x16, multi-pod 2x16x16).
+"""Mesh construction: production meshes (single-pod 16x16, multi-pod
+2x16x16), small test meshes, and the launchers' ``--mesh`` meshes.
 
-A function, not a module-level constant: importing this module never
+Functions, not module-level constants: importing this module never
 touches jax device state (required so smoke tests see 1 device).
+
+Every mesh here has ``Auto`` axes.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which the sharding rules of
+``repro.parallel.sharding`` (``with_sharding_constraint`` by logical
+axis, GSPMD-propagated gathers) are refused.
 """
 
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """Auto-axis mesh of ``shape`` over the first ``prod(shape)`` of
+    ``devices`` (default: ``jax.devices()``)."""
     n = int(np.prod(shape))
-    devices = jax.devices()
+    devices = list(jax.devices() if devices is None else devices)
     if len(devices) < n:
         raise RuntimeError(
-            f"mesh {shape} needs {n} devices but only {len(devices)} are "
-            "available -- run under dryrun.py (which forces 512 host "
-            "devices) or set XLA_FLAGS=--xla_force_host_platform_device_"
-            f"count={n}")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+            f"mesh {tuple(shape)} needs {n} devices but only "
+            f"{len(devices)} are available")
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def make_production_mesh(*, multi_pod: bool = False,
+                         devices: Optional[Sequence] = None) -> Mesh:
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return make_mesh(shape, axes, devices)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh for multi-device unit tests (subprocess-forced devices)."""
-    n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes)

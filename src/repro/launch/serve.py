@@ -1,16 +1,18 @@
 """Serving launcher: quantized continuous-batching inference.
 
 ``python -m repro.launch.serve --arch qwen2.5-1.5b --smoke --quant q8_0``
-spins up the lane engine on synthetic prompts and reports prefill/decode
-throughput plus the capability-model prediction for the target device
-profile (the paper's llama-bench workflow, framework-side).
+spins up the lane engine on synthetic prompts and reports the served
+tokens, the device they ran on and the compile counters, plus the
+capability-model prediction for a device profile (the paper's
+llama-bench workflow, framework-side).  ``main(argv)`` returns the live
+engine, so a caller can drive it further in the same process.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import time
+from typing import List, NamedTuple
 
 import jax
 import numpy as np
@@ -18,27 +20,41 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.device_profile import get_profile
 from repro.core.perf_model import InferencePerfModel, LLMSpec
+from repro.launch.compile_cache import setup_compilation_cache
 from repro.models import build_model
 from repro.serving import Request, ServeEngine, dequantize_params, \
     quantize_params
 
 
-def setup_compilation_cache() -> str | None:
-    """Point XLA at the persistent compilation cache when the canonical
-    environment (``scripts/serve_env.sh``) exported one.
+class ServeRun(NamedTuple):
+    """What :func:`main` served: its parsed arguments, the engine (still
+    live, so a caller can serve more requests on it), and the requests
+    with their generated tokens."""
+    args: argparse.Namespace
+    engine: ServeEngine
+    requests: List[Request]
 
-    With the cache warm, a relaunch reuses compiled prefill/decode
-    executables for every shape bucket it has seen before; the compile
-    counters printed at the end make a cold cache visible.  Zero
-    ``min_compile_time`` so even the tiny smoke-config executables are
-    persisted (the default threshold skips sub-second compiles).
-    """
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return None
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return cache_dir
+
+def synthetic_requests(args: argparse.Namespace, vocab_size: int, *,
+                       seed: int = 0, uid0: int = 0) -> List[Request]:
+    """``args.requests`` random prompts of ``args.prompt_len`` tokens.
+    With ``--prefix-sharing`` their first tokens are one head shared by
+    every call (``--shared-prefix-len``, default half the prompt); the
+    rest are drawn from ``seed``."""
+    head_len = 0
+    if args.prefix_sharing:
+        head_len = args.shared_prefix_len \
+            if args.shared_prefix_len is not None else args.prompt_len // 2
+        head_len = max(min(head_len, args.prompt_len - 1), 0)
+    head = np.random.default_rng(0).integers(0, vocab_size, head_len)
+    rng = np.random.default_rng((1, seed))
+    return [Request(uid=uid0 + i,
+                    prompt=np.concatenate(
+                        [head, rng.integers(0, vocab_size,
+                                            args.prompt_len - head_len)]
+                    ).astype(np.int32),
+                    max_new_tokens=args.gen)
+            for i in range(args.requests)]
 
 
 def main(argv=None):
@@ -65,9 +81,8 @@ def main(argv=None):
                     help="device profile for the analytic prediction")
     args = ap.parse_args(argv)
 
-    cache_dir = setup_compilation_cache()
-    if cache_dir:
-        print(f"compilation cache: {cache_dir}")
+    print(f"compilation cache: {setup_compilation_cache()}")
+    dev = jax.devices()[0]
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -77,23 +92,11 @@ def main(argv=None):
         print(f"quantized {stats['quantized']} weight matrices "
               f"({stats['quantized_bytes']/1e6:.1f} MB vs dense "
               f"{stats['dense_bytes']/1e6:.1f} MB kept dense)")
-        params = dequantize_params(qp)   # dense exec path on CPU
+        # qmatmul is not on the served path: serve the round-tripped
+        # dense weights
+        params = dequantize_params(qp)
 
-    rng = np.random.default_rng(0)
-    head_len = 0
-    if args.prefix_sharing:
-        head_len = args.shared_prefix_len \
-            if args.shared_prefix_len is not None else args.prompt_len // 2
-        head_len = max(min(head_len, args.prompt_len - 1), 0)
-    head = rng.integers(0, cfg.vocab_size, head_len).astype(np.int32)
-    reqs = [Request(uid=i,
-                    prompt=np.concatenate(
-                        [head,
-                         rng.integers(0, cfg.vocab_size,
-                                      args.prompt_len - head_len
-                                      ).astype(np.int32)]),
-                    max_new_tokens=args.gen)
-            for i in range(args.requests)]
+    reqs = synthetic_requests(args, cfg.vocab_size)
 
     paged = args.paged or args.prefix_sharing
     max_len = args.prompt_len + args.gen + 8
@@ -108,7 +111,8 @@ def main(argv=None):
     dt = time.time() - t0
     n_gen = sum(len(r.generated) for r in reqs)
     print(f"served {len(reqs)} requests, {n_gen} tokens in {dt:.2f}s "
-          f"({n_gen/dt:.1f} tok/s measured on CPU)")
+          f"({n_gen/dt:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          f"compiles included)")
     print(f"compiles: prefill {engine.stats['prefill_compiles']}, "
           f"decode {engine.stats['decode_compiles']} "
           f"(steady state re-serves from the jit cache)")
@@ -118,7 +122,6 @@ def main(argv=None):
               f"{s['prefix_tokens_matched']} prompt tokens served from "
               f"cached pages, {s['prefix_pages_saved']} prefill pages "
               f"saved, {s['prefix_cow_copies']} copy-on-write splits")
-        engine.prefix_cache.flush()
 
     prof = get_profile(args.profile)
     spec = LLMSpec(name=cfg.name, n_layers=cfg.n_layers, d_model=cfg.d_model,
@@ -127,9 +130,10 @@ def main(argv=None):
                    tied_embeddings=cfg.tie_embeddings)
     m = InferencePerfModel(prof, spec)
     fmt = args.quant or "f16"
-    print(f"capability-model prediction on {prof.name}: "
+    print(f"capability-model prediction (analytic) on {prof.name}: "
           f"prefill {m.prefill(fmt).tokens_per_s:,.0f} tok/s, "
           f"decode {m.decode(fmt).tokens_per_s:,.0f} tok/s ({fmt})")
+    return ServeRun(args, engine, reqs)
 
 
 if __name__ == "__main__":

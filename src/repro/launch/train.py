@@ -10,22 +10,57 @@ resume-from-latest.
 from __future__ import annotations
 
 import argparse
-import os
+import functools
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import Mesh
 
 from repro.checkpoint import AsyncCheckpointer, restore_latest
 from repro.configs import get_config
 from repro.data import DataConfig, DataLoader
+from repro.launch.compile_cache import setup_compilation_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig
-from repro.parallel.sharding import (batch_shardings, param_shardings,
-                                     use_mesh)
+from repro.parallel.sharding import param_shardings, use_mesh
 from repro.train import TrainConfig, init_train_state, make_train_step
 from repro.train.fault_tolerance import StragglerMonitor
+
+
+def parse_mesh(spec: Optional[str]) -> Optional[Mesh]:
+    """``--mesh`` value -> mesh over the local devices: '4,2' is
+    (data=4, model=2), '8' is (data=8); None or '' is no mesh."""
+    if not spec:
+        return None
+    shape = tuple(int(x) for x in spec.split(","))
+    return make_mesh(shape, ("data", "model")[:len(shape)])
+
+
+def init_state(model, mesh: Optional[Mesh]):
+    """Train state from seed 0.  Under a mesh it is initialized already
+    sharded, so no one device has to hold all of it."""
+    key = jax.random.PRNGKey(0)
+    init = functools.partial(init_train_state, model)
+    if mesh is None:
+        return init(key)
+    sh = param_shardings(mesh, jax.eval_shape(init, key))
+    return jax.jit(init, out_shardings=sh)(key)
+
+
+def jit_train_step(step_fn, state, mesh: Optional[Mesh]):
+    """Jit ``step_fn`` with the train state donated.  Under a mesh the
+    state is sharded by the FSDP+TP rules of ``repro.parallel`` and
+    placed; call the step inside ``use_mesh(mesh)``.  Returns
+    (jitted step, placed state)."""
+    if mesh is None:
+        return jax.jit(step_fn, donate_argnums=(0,)), state
+    sh = param_shardings(mesh, state)
+    step = jax.jit(step_fn, in_shardings=(sh, None),
+                   out_shardings=(sh, None), donate_argnums=(0,))
+    return step, jax.device_put(state, sh)
 
 
 def main(argv=None):
@@ -45,6 +80,7 @@ def main(argv=None):
                     help="e.g. '4,2' -> (data=4, model=2) over local devices")
     args = ap.parse_args(argv)
 
+    setup_compilation_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     tcfg = TrainConfig(
@@ -53,14 +89,9 @@ def main(argv=None):
         remat=not args.smoke, microbatches=args.microbatches)
     step_fn = make_train_step(cfg, tcfg)
 
-    mesh = None
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-        names = ("data", "model")[:len(shape)]
-        mesh = jax.make_mesh(shape, names,
-                             devices=jax.devices()[:int(np.prod(shape))])
+    mesh = parse_mesh(args.mesh)
 
-    state = init_train_state(model, jax.random.PRNGKey(0))
+    state = init_state(model, mesh)
     start = 0
     ckpt = None
     if args.ckpt_dir:
@@ -70,13 +101,7 @@ def main(argv=None):
             start, state = got, restored
             print(f"resumed from step {start}")
 
-    if mesh is not None:
-        sh = param_shardings(mesh, state)
-        step_fn = jax.jit(step_fn, in_shardings=(sh, None),
-                          out_shardings=(sh, None), donate_argnums=(0,))
-        state = jax.device_put(state, sh)
-    else:
-        step_fn = jax.jit(step_fn, donate_argnums=(0,))
+    step_fn, state = jit_train_step(step_fn, state, mesh)
 
     data = DataLoader(DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                                  global_batch=args.batch), start_step=start)

@@ -67,8 +67,7 @@ def attention_forward(p, x: jnp.ndarray, cfg: ModelConfig, *,
     kt = constrain(k.transpose(0, 2, 1, 3), "batch", None, "seq", None)
     vt = constrain(v.transpose(0, 2, 1, 3), "batch", None, "seq", None)
     out = flash_attention(qt, kt, vt, causal=causal,
-                          window=cfg.sliding_window,
-                          use_pallas=cfg.use_pallas)
+                          window=cfg.sliding_window)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
     if return_kv:
@@ -133,8 +132,7 @@ def attention_decode(p, x: jnp.ndarray, cfg: ModelConfig, k_cache, v_cache,
         v_cache = put(v_cache, v, slot)
         k_eff, v_eff = k_cache, v_cache
     eff_len = jnp.minimum(cache_len + 1, smax)
-    out = decode_attention(q, k_eff, v_eff, eff_len.astype(jnp.int32),
-                           use_pallas=cfg.use_pallas)
+    out = decode_attention(q, k_eff, v_eff, eff_len.astype(jnp.int32))
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
     if quant:
@@ -193,8 +191,7 @@ def attention_decode_paged(p, x: jnp.ndarray, cfg: ModelConfig, k_pages,
     eff_len = jnp.minimum(cache_len + 1, cap)
     out = decode_attention_paged(q, k_eff, v_eff,
                                  block_tables.astype(jnp.int32),
-                                 eff_len.astype(jnp.int32),
-                                 use_pallas=cfg.use_pallas)
+                                 eff_len.astype(jnp.int32))
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
     if quant:

@@ -73,8 +73,6 @@ class ModelConfig:
     # vlm: number of prefix vision tokens the stub frontend provides
     n_vision_tokens: int = 0
     dtype: str = "bfloat16"
-    # kernels: use Pallas paths (TPU) vs jnp reference paths (CPU tests)
-    use_pallas: bool = False
     # decode KV cache quantization: None | "int8" (per-token-per-head
     # symmetric scales; beyond-paper application of C4)
     kv_quant: Optional[str] = None

@@ -102,7 +102,7 @@ def _cross_attention(p, x, enc_kv, cfg: ModelConfig):
         q = q + p["bq"].astype(x.dtype)
     q = q.reshape(b, s, cfg.n_heads, cfg.hd).transpose(0, 2, 1, 3)
     k, v = enc_kv
-    out = flash_attention(q, k, v, causal=False, use_pallas=cfg.use_pallas)
+    out = flash_attention(q, k, v, causal=False)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.hd)
     return jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
 
@@ -206,7 +206,7 @@ def whisper_decode_step(params: Params, cfg: ModelConfig, cache: Params,
         if cfg.qkv_bias:
             q = q + p["cross_attn"]["bq"].astype(x.dtype)
         q = q.reshape(b, cfg.n_heads, cfg.hd)
-        co = decode_attention(q, ck, cv, enc_len, use_pallas=cfg.use_pallas)
+        co = decode_attention(q, ck, cv, enc_len)
         co = co.reshape(b, 1, cfg.n_heads * cfg.hd)
         x = x + jnp.einsum("bsh,hd->bsd", co,
                            p["cross_attn"]["wo"].astype(x.dtype))

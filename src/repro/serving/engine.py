@@ -694,6 +694,15 @@ class ServeEngine:
             params, cache, tokens, rng, remaining, lane_seed, tok_idx,
             n_steps=n_steps, temperature=temperature, len_cap=len_cap)
 
+    def decode_program_text(self) -> str:
+        """Compiled text of the ``dispatch_n``-step decode dispatch at
+        the live arguments: which kernels the compiler placed in it (a
+        Pallas kernel shows as ``tpu_custom_call``)."""
+        return self._decode_n.lower(
+            self.params, self.cache, self._next_token, self._rng_decode,
+            self._remaining, self._lane_seed, self._tok_idx,
+            n_steps=self.dispatch_n).compile().as_text()
+
     # -- telemetry --------------------------------------------------------
     @property
     def stats(self) -> StatsView:

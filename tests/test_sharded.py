@@ -105,20 +105,19 @@ def test_sharded_decode_step():
 def test_compressed_psum_shard_map():
     print(_run("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_test_mesh
     from repro.optim.compression import compressed_psum
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"),
-                         devices=jax.devices()[:8])
+    mesh = make_test_mesh((2, 4), ("pod", "data"))
     g = jax.random.normal(jax.random.PRNGKey(0), (2, 4096)) * 1e-3
 
     def f(gl):
         mean, resid = compressed_psum(gl[0], axis="pod")
         return mean[None], resid[None]
 
-    mean, resid = shard_map(f, mesh=mesh, in_specs=P("pod"),
-                            out_specs=P("pod"))(g)
+    mean, resid = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                out_specs=P("pod"))(g)
     exact = jnp.mean(g, axis=0)
     err = float(jnp.max(jnp.abs(mean[0] - exact)) /
                 (jnp.max(jnp.abs(exact)) + 1e-12))
