@@ -1,0 +1,20 @@
+"""Chip peaks, keyed by JAX's ``device_kind`` (``bench/peaks.json``).
+A kind that is not in the table is an error, never a default."""
+
+from __future__ import annotations
+
+import json
+import os
+
+PEAKS_FILE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "peaks.json")
+
+
+def peaks(device_kind: str) -> dict:
+    with open(PEAKS_FILE) as f:
+        table = json.load(f)
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       f"{PEAKS_FILE}; add its row with a source")
+    return table[device_kind]

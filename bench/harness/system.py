@@ -1,0 +1,77 @@
+"""The system under test: ``repro.serving.ServeEngine`` built from a
+configuration file.  This is the one module of the benchmark that
+imports the program; everything else takes what it measures from here.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+from harness.model_config import Arch
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if os.path.join(REPO, "src") not in sys.path:
+    sys.path.insert(0, os.path.join(REPO, "src"))
+
+from repro.launch.compile_cache import setup_compilation_cache  # noqa: E402,F401
+from repro.models.common import ModelConfig  # noqa: E402
+from repro.serving import Request, ServeEngine  # noqa: E402,F401
+
+#: engine counters the harness reads (registry names under the engine's
+#: own prefix)
+COUNTERS = ("decode.steps", "tokens.generated", "prefix.tokens_matched",
+            "prefix.hits", "prefix.misses", "prefix.cow_copies")
+
+
+def program_config(arch: Arch) -> ModelConfig:
+    return ModelConfig(
+        name=arch.name, family="dense", n_layers=arch.n_layers,
+        d_model=arch.d_model, n_heads=arch.n_heads,
+        n_kv_heads=arch.n_kv_heads, d_ff=arch.d_ff,
+        vocab_size=arch.vocab_size, head_dim=arch.head_dim_override,
+        qkv_bias=arch.qkv_bias, norm=arch.norm,
+        tie_embeddings=arch.tie_embeddings, rope_theta=arch.rope_theta,
+        max_seq_len=arch.max_position_embeddings,
+        dtype=arch.compute_dtype)
+
+
+def build_engine(arch: Arch, params) -> ServeEngine:
+    s = arch.serving
+    return ServeEngine(
+        program_config(arch), params, n_lanes=s["n_lanes"],
+        max_len=s["max_len"], temperature=s["temperature"],
+        dispatch_n=s["dispatch_n"], paged=True, page_size=s["page_size"],
+        n_pages=s["n_pages"], prefix_sharing=s["prefix_sharing"],
+        name="serve")
+
+
+def counters(engine: ServeEngine) -> dict:
+    """The engine's registry counters, by their names without the
+    engine's prefix."""
+    return {c: engine.registry[f"{engine.name}.{c}"].value
+            for c in COUNTERS}
+
+
+def next_token_array(engine: ServeEngine):
+    """The device array of each lane's next input token.  Right after
+    ``admit`` the admitted lane holds the answer's first token, sampled
+    from the prefill logits; the engine replaces the array (it never
+    writes into it), so a reference taken then keeps that token."""
+    return engine._next_token
+
+
+def lane_remaining(engine: ServeEngine, lane: int) -> int:
+    """Tokens the lane still owes (host mirror, no sync)."""
+    return int(engine._remaining_host[lane])
+
+
+def pages_held(engine: ServeEngine, lanes) -> int:
+    """Pool pages the given lanes map (a page shared by several counted
+    once) plus the pages they still have reserved: what admission had to
+    find free for them."""
+    mapped = set()
+    for i in lanes:
+        mapped.update(engine._lane_pages[i])
+    return len(mapped) + sum(engine._lane_reserved[i] for i in lanes)
