@@ -31,12 +31,27 @@ __all__ = [
 
 #: closed intervals on engine/lane/board tracks
 SPAN_NAMES: Dict[str, str] = {
-    "admit": "one admission: prefill + lane/page setup (uid)",
-    "prefill.bucket": "batched prefill at a padded bucket length",
+    "admit.reserve": "admission's prefix match, cache trim and page "
+                     "reservation, before admit (uid, lane, ok)",
+    "admit": "one admission: prefill + lane/page setup (uid, lane)",
+    "prefill.bucket": "host enqueue of the batched prefill at a padded "
+                      "bucket length plus the eager page scatter, not "
+                      "device time (lane)",
+    "prefill.launch": "enqueue of the jitted batched prefill (lane)",
+    "prefill.scatter": "eager per-page scatter of the prompt KV (lane)",
+    "prefix.insert": "radix prompt-cache insert of a lane's prompt "
+                     "pages (lane)",
     "prefix.tail_prefill": "prefix-hit tail streamed through decode",
     "prefix.cow": "copy-on-write split of a shared page",
     "decode.dispatch": "one multi-step jitted decode dispatch "
                        "(n_steps, n_live, uids)",
+    "decode.prepare": "page mapping, block-table writes and sanitizer "
+                      "shadow writes before the launch",
+    "decode.launch": "the jitted multi-step decode call until it "
+                     "returns",
+    "decode.wait": "the device_get drain of a dispatch's tokens",
+    "decode.retire": "per-lane token bookkeeping, first_token/retire "
+                     "instants, the SLO feed and lane release",
     "preempt.evict": "lane checkpointed off the board (uid, n_pages)",
     "preempt.restore": "checkpoint scattered back onto a lane "
                        "(uid, n_pages)",
@@ -84,6 +99,12 @@ METRIC_FAMILIES: Dict[str, str] = {
                              "disabled, hwm, allocs, frees, shared, "
                              "cow_splits)",
     "<engine>.prefix.cached_pages": "pages the radix prompt cache holds",
+    "<engine>.decode.lane_steps": "decode steps of live lanes: per "
+                                  "dispatch, the sum over live lanes of "
+                                  "min(n_steps, remaining)",
+    "<engine>.kv.reserved_page_steps": "per dispatch, n_steps times the "
+                                       "pool's pages reserved and not "
+                                       "yet allocated",
     "modelpool.*": "weight-pool gauges (bytes.used, bytes.free, "
                    "residents)",
     "fleet.*": "fleet-sim gauges and fault counters (retry.attempts, "

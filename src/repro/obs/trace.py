@@ -14,15 +14,24 @@ A :class:`SpanTracer` records closed intervals (``Span``) on named
   intervals, which is how :class:`~repro.fleet.sim.FleetSim` emits
   deterministic spans stamped with simulated seconds.
 
+Every :meth:`span` is also a ``jax.profiler.TraceAnnotation`` named
+``<engine>.<span>`` (the engine is the track up to its first ``/``:
+``serve/lane3`` + ``admit`` -> ``serve.admit``), carrying the span's
+``uid``, ``lane`` and ``n_steps`` args where it has them.  The
+annotation is written whether or not the tracer is enabled: while no
+profiler runs it costs 1-2 microseconds, and while one does, the
+engine's spans land in the device trace on the device's clock.  The
+sim-clock path writes none.
+
 Exports Chrome-trace / Perfetto JSON (:meth:`export_chrome_trace`,
 load the file at https://ui.perfetto.dev) and feeds per-span durations
 into a :class:`~repro.obs.metrics.MetricsRegistry` histogram
 (``span.<name>.seconds``) when one is attached, which is where the
 bench's per-phase p50/p99 come from.
 
-A disabled tracer (``enabled=False``) costs one attribute check and a
-shared null context manager per call site -- engines construct one by
-default so call sites never branch.
+A disabled tracer (``enabled=False``) records nothing in memory: its
+spans are the profiler annotation alone, its instants one attribute
+check -- engines construct one by default so call sites never branch.
 """
 
 from __future__ import annotations
@@ -33,9 +42,20 @@ import json
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.metrics import MetricsRegistry
 
-_NULL_CM = contextlib.nullcontext()
+#: span args an annotation carries into the profiler's trace
+ANNOTATED_ARGS = frozenset(("uid", "lane", "n_steps"))
+
+
+class _Annotation(TraceAnnotation):
+    """A span's profiler annotation.  Entering it yields ``None``, which
+    is all a disabled tracer's span yields."""
+
+    def __enter__(self):
+        TraceAnnotation.__enter__(self)
 
 
 @dataclasses.dataclass
@@ -69,12 +89,10 @@ class SpanTracer:
 
     def __init__(self, enabled: bool = True,
                  clock: Optional[Callable[[], float]] = None,
-                 registry: Optional[MetricsRegistry] = None,
-                 span_metric_prefix: str = "span"):
+                 registry: Optional[MetricsRegistry] = None):
         self.enabled = enabled
         self.clock = clock or time.perf_counter
         self.registry = registry
-        self.span_metric_prefix = span_metric_prefix
         self.spans: List[Span] = []
         self.instants: List[Instant] = []
         self._stacks: Dict[str, List[Span]] = {}
@@ -85,24 +103,31 @@ class SpanTracer:
 
     # -- recording ------------------------------------------------------
     def span(self, name: str, track: str = "main", **args):
-        """Context manager timing a host-side block; no-op when disabled."""
+        """Context manager timing a host-side block.  It yields the
+        :class:`Span` (``None`` when disabled, where only the profiler
+        annotation is written)."""
+        ann = _Annotation(f"{track.partition('/')[0]}.{name}",
+                          **{k: v for k, v in args.items()
+                             if k in ANNOTATED_ARGS})
         if not self.enabled:
-            return _NULL_CM
-        return self._span_cm(name, track, args)
+            return ann
+        return self._span_cm(name, track, args, ann)
 
     @contextlib.contextmanager
-    def _span_cm(self, name: str, track: str, args: Dict[str, object]):
-        sp = Span(name=name, track=track, t0=self.clock(), args=args)
-        stack = self._stacks.setdefault(track, [])
-        stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t1 = self.clock()
-            popped = stack.pop()
-            assert popped is sp, f"span nesting violated on {track!r}"
-            self.spans.append(sp)
-            self._observe(sp)
+    def _span_cm(self, name: str, track: str, args: Dict[str, object],
+                 ann: TraceAnnotation):
+        with ann:
+            sp = Span(name=name, track=track, t0=self.clock(), args=args)
+            stack = self._stacks.setdefault(track, [])
+            stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.t1 = self.clock()
+                popped = stack.pop()
+                assert popped is sp, f"span nesting violated on {track!r}"
+                self.spans.append(sp)
+                self._observe(sp)
 
     def add_span(self, name: str, t0: float, t1: float,
                  track: str = "main", **args) -> Optional[Span]:
@@ -143,7 +168,7 @@ class SpanTracer:
     def _observe(self, sp: Span) -> None:
         if self.registry is not None:
             self.registry.histogram(
-                f"{self.span_metric_prefix}.{sp.name}.seconds",
+                f"span.{sp.name}.seconds",
                 help=f"host seconds inside {sp.name!r} spans",
             ).observe(sp.duration_s)
         if self.on_span is not None:

@@ -171,6 +171,11 @@ class PagePool:
         """Pages currently part of the pool (physical minus disabled)."""
         return self.n_pages - len(self._disabled)
 
+    @property
+    def n_reserved(self) -> int:
+        """Pages promised to admitted requests and not yet allocated."""
+        return self._reserved
+
     def available(self) -> int:
         """Pages admissible to NEW requests (free minus promised)."""
         return len(self._free) - self._reserved
@@ -322,7 +327,7 @@ class PagePool:
         registry.gauge(f"{prefix}.pages.in_use", fn=lambda: self.n_in_use,
                        help="pages allocated to live lanes")
         registry.gauge(f"{prefix}.pages.reserved",
-                       fn=lambda: self._reserved,
+                       fn=lambda: self.n_reserved,
                        help="pages promised to admitted requests")
         registry.gauge(f"{prefix}.pages.disabled",
                        fn=lambda: self.n_disabled,
@@ -503,12 +508,14 @@ class ServeEngine:
     STATS_SCHEMA = {
         "decode_dispatches": "decode.dispatches",
         "decode_steps": "decode.steps",
+        "decode_lane_steps": "decode.lane_steps",
         "decode_compiles": "decode.compiles",
         "generated_tokens": "tokens.generated",
         "prefill_compiles": "prefill.compiles",
         "ssm_prefill_compiles": "prefill.ssm_compiles",
         "kv_pages_hwm": "kv.pages_hwm",
         "kv_admit_blocked": "kv.admit_blocked",
+        "kv_reserved_page_steps": "kv.reserved_page_steps",
         "preemptions": "preempt.evictions",
         "restores": "preempt.restores",
         "pages_migrated": "preempt.pages_migrated",
@@ -761,38 +768,45 @@ class ServeEngine:
         lane = lanes[0]
         hit: Optional[PrefixHit] = None
         if self.paged:
-            need = self.admission_pages(req)
-            reserve = need
-            if self.prefix_cache is not None:
-                hit = self._prefix_match(req)
-                # every FULL matched page is a page this request never
-                # allocates: the reservation (what admission gates on)
-                # shrinks by exactly that, which is the effective-
-                # admission gain the bench measures
-                reserve = need - hit.n_full
-            if not self.pool.reserve(reserve):
-                if self.prefix_cache is not None \
-                        and self.prefix_cache.n_pages:
-                    # under pool pressure, cached-but-unmapped prefix
-                    # pages are the first bytes to go; eviction may
-                    # drop matched nodes (their pages can be reissued
-                    # once the last holder lets go), so re-match after
-                    self._trim_prefix_cache(reserve)
+            with self.tracer.span("admit.reserve",
+                                  track=self.lane_track(lane),
+                                  uid=req.uid, lane=lane) as sp:
+                need = self.admission_pages(req)
+                reserve = need
+                if self.prefix_cache is not None:
                     hit = self._prefix_match(req)
+                    # every FULL matched page is a page this request
+                    # never allocates: the reservation (what admission
+                    # gates on) shrinks by exactly that, which is the
+                    # effective-admission gain the bench measures
                     reserve = need - hit.n_full
-                if not self.pool.reserve(reserve):
-                    # a lane is free but the KV bytes are not: admission
-                    # is gated on pages, the caller retries after
-                    # retirements.  Counted once per blocked EPISODE
-                    # (not per retry), so the stat is dispatch-
-                    # granularity invariant.
-                    if req.uid not in self._blocked_uids:
-                        self._blocked_uids.add(req.uid)
-                        self.stats["kv_admit_blocked"] += 1
-                        self.tracer.instant("admit.blocked",
-                                            track=self.lane_track(lane),
-                                            uid=req.uid, need_pages=need)
-                    return False
+                ok = self.pool.reserve(reserve)
+                if not ok:
+                    if self.prefix_cache is not None \
+                            and self.prefix_cache.n_pages:
+                        # under pool pressure, cached-but-unmapped
+                        # prefix pages are the first bytes to go;
+                        # eviction may drop matched nodes (their pages
+                        # can be reissued once the last holder lets
+                        # go), so re-match after
+                        self._trim_prefix_cache(reserve)
+                        hit = self._prefix_match(req)
+                        reserve = need - hit.n_full
+                    ok = self.pool.reserve(reserve)
+                if sp is not None:
+                    sp.args["ok"] = ok
+            if not ok:
+                # a lane is free but the KV bytes are not: admission is
+                # gated on pages, the caller retries after retirements.
+                # Counted once per blocked EPISODE (not per retry), so
+                # the stat is dispatch-granularity invariant.
+                if req.uid not in self._blocked_uids:
+                    self._blocked_uids.add(req.uid)
+                    self.stats["kv_admit_blocked"] += 1
+                    self.tracer.instant("admit.blocked",
+                                        track=self.lane_track(lane),
+                                        uid=req.uid, need_pages=need)
+                return False
             self._blocked_uids.discard(req.uid)
         if self.slo is not None and req.uid not in self._admit_t:
             # TTFT starts at first successful admission (re-admission
@@ -801,7 +815,7 @@ class ServeEngine:
         with flight_guard(self.flight, op="admit",
                           registry=self.registry), \
                 self.tracer.span("admit", track=self.lane_track(lane),
-                                 uid=req.uid):
+                                 uid=req.uid, lane=lane):
             if self.paged:
                 self._lane_reserved[lane] = reserve
                 self._lane_pages[lane] = []
@@ -945,7 +959,7 @@ class ServeEngine:
         appending to the original), and retarget the block table."""
         old = self._lane_pages[lane][idx]
         with self.tracer.span("prefix.cow", track=self.lane_track(lane),
-                              page=old):
+                              lane=lane, page=old):
             new = self.pool.cow(old, holder=lane)
             self._lane_reserved[lane] -= 1
             self._lane_pages[lane][idx] = new
@@ -988,7 +1002,7 @@ class ServeEngine:
         padded = np.zeros((bucket,), np.int32)
         padded[:tlen] = tail
         with self.tracer.span("prefix.tail_prefill",
-                              track=self.lane_track(lane),
+                              track=self.lane_track(lane), lane=lane,
                               bucket=bucket, tlen=tlen):
             logits, lane_cache = self._ssm_prefill(
                 self.params, lane_cache, jnp.asarray(padded),
@@ -1003,13 +1017,18 @@ class ServeEngine:
         bucket = _bucket_len(plen) if self.prefill_bucketing else plen
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :plen] = prompt
-        with self.tracer.span("prefill.bucket",
-                              track=self.lane_track(lane),
+        track = self.lane_track(lane)
+        with self.tracer.span("prefill.bucket", track=track, lane=lane,
                               bucket=bucket, plen=plen):
-            logits, kv = self._prefill(self.params, jnp.asarray(padded),
-                                       jnp.asarray([plen - 1], jnp.int32))
-            self._scatter_prompt_paged(kv, lane, plen,
-                                       first_block=hit.n_full)
+            with self.tracer.span("prefill.launch", track=track,
+                                  lane=lane):
+                logits, kv = self._prefill(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray([plen - 1], jnp.int32))
+            with self.tracer.span("prefill.scatter", track=track,
+                                  lane=lane):
+                self._scatter_prompt_paged(kv, lane, plen,
+                                           first_block=hit.n_full)
         self.cache["len"] = self.cache["len"].at[lane].set(plen)
         self._set_first_token(logits, lane)
 
@@ -1017,10 +1036,12 @@ class ServeEngine:
         """Offer the freshly prefilled lane's prompt pages to the radix
         cache (the cache takes its own refs on pages it keeps)."""
         prompt = self._trunc_prompt(req)
-        self.prefix_cache.insert(
-            np.asarray(prompt), int(prompt.shape[0]),
-            self._lane_pages[lane],
-            allow_partial=self.cfg.kv_quant != "int8")
+        with self.tracer.span("prefix.insert", track=self.lane_track(lane),
+                              lane=lane):
+            self.prefix_cache.insert(
+                np.asarray(prompt), int(prompt.shape[0]),
+                self._lane_pages[lane],
+                allow_partial=self.cfg.kv_quant != "int8")
 
     def _prefill_into_lane(self, req: Request, lane: int) -> None:
         prompt = req.prompt
@@ -1036,16 +1057,21 @@ class ServeEngine:
         bucket = _bucket_len(plen) if self.prefill_bucketing else plen
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :plen] = prompt
-        with self.tracer.span("prefill.bucket",
-                              track=self.lane_track(lane),
+        track = self.lane_track(lane)
+        with self.tracer.span("prefill.bucket", track=track, lane=lane,
                               bucket=bucket, plen=plen):
-            logits, kv = self._prefill(self.params, jnp.asarray(padded),
-                                       jnp.asarray([plen - 1], jnp.int32))
+            with self.tracer.span("prefill.launch", track=track,
+                                  lane=lane):
+                logits, kv = self._prefill(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray([plen - 1], jnp.int32))
             if kv is not None:
-                if self.paged:
-                    self._scatter_prompt_paged(kv, lane, plen)
-                else:
-                    self._scatter_prompt_dense(kv, lane, plen)
+                with self.tracer.span("prefill.scatter", track=track,
+                                      lane=lane):
+                    if self.paged:
+                        self._scatter_prompt_paged(kv, lane, plen)
+                    else:
+                        self._scatter_prompt_dense(kv, lane, plen)
         if "ssm_h" in self.cache:
             # SSM state is rebuilt by streaming the prompt through the
             # decode path (exactly once, O(len) state updates).
@@ -1244,40 +1270,63 @@ class ServeEngine:
                     "decode.dispatch", track=self.name, n_steps=n,
                     n_live=len(live),
                     uids=tuple(self.lane_req[i].uid for i in live)):
-            if self.paged:
-                # map the pages this block can write into BEFORE the
-                # jitted dispatch (the scan itself never touches the
-                # allocator); the admission-time reservation makes this
-                # infallible
-                for lane in live:
-                    steps = min(n, int(self._remaining_host[lane]))
-                    self._map_pages(lane, self._pages_needed(
-                        int(self._len_host[lane]) + steps + 1))
-                s = self._sanitizer
-                if s is not None:
-                    for lane in live:
-                        steps = min(n, int(self._remaining_host[lane]))
-                        start = int(self._len_host[lane])
-                        if self.cfg.sliding_window is not None:
-                            # ring writes rotate within the fixed set
-                            pages = list(self._lane_pages[lane])
-                        else:
-                            pages = self._lane_pages[lane][
-                                start // self.page_size:
-                                self._pages_needed(start + steps + 1)]
-                        if pages:
-                            s.record("write", lane=lane, pages=pages,
-                                     kind="decode")
-            (toks, valid, self._next_token, self.cache, self._remaining,
-             self._tok_idx) = self._decode_n(
-                self.params, self.cache, self._next_token,
-                self._rng_decode, self._remaining, self._lane_seed,
-                self._tok_idx, n_steps=n)
+            with self.tracer.span("decode.prepare", track=self.name):
+                steps = [min(n, int(self._remaining_host[lane]))
+                         for lane in live]
+                if self.paged:
+                    # map the pages this block can write into BEFORE the
+                    # jitted dispatch (the scan itself never touches the
+                    # allocator); the admission-time reservation makes
+                    # this infallible
+                    for lane, k in zip(live, steps):
+                        self._map_pages(lane, self._pages_needed(
+                            int(self._len_host[lane]) + k + 1))
+                    s = self._sanitizer
+                    if s is not None:
+                        for lane, k in zip(live, steps):
+                            start = int(self._len_host[lane])
+                            if self.cfg.sliding_window is not None:
+                                # ring writes rotate within the fixed set
+                                pages = list(self._lane_pages[lane])
+                            else:
+                                pages = self._lane_pages[lane][
+                                    start // self.page_size:
+                                    self._pages_needed(start + k + 1)]
+                            if pages:
+                                s.record("write", lane=lane, pages=pages,
+                                         kind="decode")
+                    # pages promised to live lanes and not yet mapped,
+                    # idle for each of the dispatch's steps
+                    self.stats["kv_reserved_page_steps"] += (
+                        n * self.pool.n_reserved)
+                self.stats["decode_lane_steps"] += sum(steps)
+            with self.tracer.span("decode.launch", track=self.name):
+                (toks, valid, self._next_token, self.cache,
+                 self._remaining, self._tok_idx) = self._decode_n(
+                    self.params, self.cache, self._next_token,
+                    self._rng_decode, self._remaining, self._lane_seed,
+                    self._tok_idx, n_steps=n)
             self.stats["decode_dispatches"] += 1
             self.stats["decode_steps"] += n
             # one host transfer drains the whole block
-            toks_h, valid_h, rem_h = jax.device_get(
-                (toks, valid, self._remaining))
+            with self.tracer.span("decode.wait", track=self.name):
+                toks_h, valid_h, rem_h = jax.device_get(
+                    (toks, valid, self._remaining))
+        with self.tracer.span("decode.retire", track=self.name):
+            out = self._retire_block(live, toks_h, valid_h, rem_h,
+                                     t_disp0)
+        if self._sanitizer is not None:
+            # dispatch boundary: shadow state must equal the real pool
+            with flight_guard(self.flight, op="sanitizer.crosscheck",
+                              registry=self.registry):
+                self._sanitizer.crosscheck(self.pool)
+        return out
+
+    def _retire_block(self, live: List[int], toks_h, valid_h, rem_h,
+                      t_disp0: float) -> Dict[int, List[int]]:
+        """Host bookkeeping after a dispatch drains: hand each live
+        lane its tokens, mark first tokens, feed the SLO monitor, and
+        release the lanes whose budget ran out."""
         self._remaining_host = np.asarray(rem_h, np.int64)
         slo = self.slo
         if slo is not None:
@@ -1314,11 +1363,6 @@ class ServeEngine:
                 self._release_lane(lane)
         if slo is not None:
             slo.step(now)
-        if self._sanitizer is not None:
-            # dispatch boundary: shadow state must equal the real pool
-            with flight_guard(self.flight, op="sanitizer.crosscheck",
-                              registry=self.registry):
-                self._sanitizer.crosscheck(self.pool)
         return out
 
     def _release_lane(self, lane: int) -> None:
@@ -1384,6 +1428,7 @@ class ServeEngine:
                           registry=self.registry), \
                 self.tracer.span("preempt.evict",
                                  track=self.lane_track(lane), uid=req.uid,
+                                 lane=lane,
                                  n_pages=len(self._lane_pages[lane])):
             pages = list(self._lane_pages[lane])
             invariant(self._scratch_page not in pages,
@@ -1460,7 +1505,7 @@ class ServeEngine:
         self._lane_pages[lane] = []
         restore_span = self.tracer.span(
             "preempt.restore", track=self.lane_track(lane),
-            uid=ckpt.uid, n_pages=ckpt.n_pages)
+            uid=ckpt.uid, lane=lane, n_pages=ckpt.n_pages)
         try:
             with restore_span:
                 self._map_pages(lane, ckpt.n_pages)
