@@ -2,9 +2,17 @@
 
 LLM decode reads one query token against a long KV cache -- *pure
 bandwidth*, the workload where the paper shows a mining GPU matching an
-A100.  The TPU kernel streams the KV cache through VMEM in key blocks
-(grid ``(B, H, Sk/bk)``) with a running-softmax state in VMEM scratch --
-i.e. FlashDecoding adapted to the HBM->VMEM hierarchy.
+A100.  The dense-cache kernels stream the KV cache through VMEM in key
+blocks (grid ``(B, H, Sk/bk)``) with a running-softmax state in VMEM
+scratch -- i.e. FlashDecoding adapted to the HBM->VMEM hierarchy.
+
+The served kernel, :func:`decode_attention_paged_pallas`, reads a global
+page pool through a block table.  Its grid is one step per lane: the
+pools stay in HBM and the kernel copies the lane's live pages itself,
+whole pages of every KV head at a time, in double-buffered groups of
+the fewest pages whose K and V bytes reach 512 KiB, and scores every
+query head of a KV head at once.  Dead lanes and table slots past a
+lane's length cost no step and move no byte.
 
 A quantized-KV variant (q8_0 per-32-block scales along the key axis)
 halves the cache traffic: the dequantize happens on the VPU right after
@@ -224,12 +232,15 @@ def kv_blocks_fetched(kv_lengths, sk: int, bk: int = 512):
 
 
 def kv_pages_fetched(kv_lengths, bt_width: int, page_size: int):
-    """Modeled page fetch count per lane for the paged kernels.
+    """Modeled page fetch count per lane for a block-table index map.
 
-    Follows the block-table index map exactly: a lane of length L DMAs
-    ``clip(ceil(L/page_size), 1, bt_width)`` pages -- identical to
-    :func:`kv_blocks_fetched` when ``page_size == bk``, which is the
-    paged-vs-dense bytes/token parity the bench pins.
+    Models :func:`decode_attention_paged_q8_pallas`, whose index map is
+    clamped to the last live page: a lane of length L DMAs
+    ``clip(ceil(L/page_size), 1, bt_width)`` pages (a dead lane still
+    pins one) -- identical to :func:`kv_blocks_fetched` when
+    ``page_size == bk``, which is the paged-vs-dense bytes/token parity
+    the bench pins.  :func:`decode_attention_paged_pallas` fetches the
+    same pages for a live lane, and none for a lane of length 0.
     """
     import numpy as np
     lens = np.asarray(kv_lengths)
@@ -245,34 +256,96 @@ def kv_pages_fetched(kv_lengths, bt_width: int, page_size: int):
 # cache is a page POOL (P, Hkv, ps, D) shared by all lanes, and each
 # lane's pages are named by a block table (B, T) of physical page ids in
 # logical order.  Both the per-lane lengths and the block tables are
-# scalar-prefetched, so the k/v index maps can (a) translate the logical
-# page index through the table and (b) keep the live-length clamp: pages
-# past ceil(len/ps) are never fetched.  Table slot ``j`` holds logical
-# positions [j*ps, (j+1)*ps) -- a sliding-window lane rotates pages at
+# scalar-prefetched.  The float kernel walks a lane's ceil(len/ps) live
+# table slots in a loop of its own and copies each page (all KV heads,
+# contiguous in the pool) from HBM itself; the q8 kernel's k/v index
+# maps translate the logical page index through the table and clamp it
+# to the last live page.  Either way pages past ceil(len/ps) are never
+# fetched.  Table slot ``j`` holds logical positions
+# [j*ps, (j+1)*ps) -- a sliding-window lane rotates pages at
 # the table level (slot = position mod window), which is safe because
 # the online softmax is permutation-invariant once every slot is live.
 
 
-def _paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                  m_ref, l_ref, *, scale: float, ps: int):
-    j = pl.program_id(2)
+# K+V bytes a DMA group of whole pages should reach: copies large enough
+# to run near HBM bandwidth, and few loop trips per lane
+_GROUP_BYTES = 512 * 1024
 
-    @pl.when(j == 0)
-    def _init():
-        _flash_init(acc_ref, m_ref, l_ref)
 
-    kv_len = len_ref[pl.program_id(0)]
+def _pages_per_group(hkv: int, ps: int, d: int, itemsize: int,
+                     t: int) -> int:
+    """Smallest page count whose K+V bytes reach ``_GROUP_BYTES``,
+    capped at the table width: one 512 KiB page of olmo-1b's bf16 pool
+    (16 KV heads x 64 x 128), eight 64 KiB pages of qwen2.5-1.5b's (2 KV
+    heads).  Double-buffered, olmo-1b's f32 pool takes the most VMEM of
+    the served pools: 2 MiB."""
+    page_bytes = 2 * hkv * ps * d * itemsize
+    return max(1, min(t, -(-_GROUP_BYTES // page_bytes)))
 
-    @pl.when(j * ps < kv_len)                  # skip dead pages entirely
-    def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # (1, d)
-        k = k_ref[0, 0].astype(jnp.float32)              # (ps, d)
-        v = v_ref[0, 0].astype(jnp.float32)              # (ps, d)
-        _flash_block(q, k, v, kv_len, j, ps, acc_ref, m_ref, l_ref)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _store():
-        _flash_store(o_ref, acc_ref, l_ref)
+def _paged_kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+                  sems, acc_ref, m_ref, l_ref, *, scale: float, ps: int,
+                  pages_per_group: int):
+    b = pl.program_id(0)
+    kv_len = len_ref[b]
+    n_live = jnp.minimum(pl.cdiv(kv_len, ps), bt_ref.shape[1])
+    n_groups = pl.cdiv(n_live, pages_per_group)
+
+    def page_copies(gi, slot, p):
+        """The K and V copies of page ``p`` of group ``gi``: one whole
+        (Hkv, ps, D) page of every KV head each, contiguous in the pool."""
+        page = bt_ref[b, gi * pages_per_group + p]
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, p],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, p],
+                                      sems.at[1, slot]))
+
+    def for_live_pages(gi, slot, fn):
+        for p in range(pages_per_group):
+            @pl.when(gi * pages_per_group + p < n_live)
+            def _():
+                for copy in page_copies(gi, slot, p):
+                    fn(copy)
+
+    _flash_init(acc_ref, m_ref, l_ref)
+    q = q_ref[0].astype(jnp.float32) * scale             # (Hkv, G, D)
+    for_live_pages(0, 0, lambda c: c.start())
+
+    def group(gi, carry):
+        slot = gi % 2
+
+        @pl.when(gi + 1 < n_groups)                      # double buffer
+        def _prefetch():
+            for_live_pages(gi + 1, 1 - slot, lambda c: c.start())
+
+        for_live_pages(gi, slot, lambda c: c.wait())
+
+        def page(i, carry):
+            j = gi * pages_per_group + i
+            k = k_buf[slot, i].astype(jnp.float32)       # (Hkv, ps, D)
+            v = v_buf[slot, i].astype(jnp.float32)
+            s = jnp.einsum("hgd,hsd->hgs", q, k,
+                           preferred_element_type=jnp.float32)
+            k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            mask = k_pos < kv_len
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev, l_prev = m_ref[...], l_ref[...]      # (Hkv, G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+            m_ref[...] = m_new
+            acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+                "hgs,hsd->hgd", p, v, preferred_element_type=jnp.float32)
+            return carry
+
+        n_here = jnp.minimum(pages_per_group, n_live - gi * pages_per_group)
+        return jax.lax.fori_loop(0, n_here, page, carry)
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    l = l_ref[...]
+    l = jnp.where(l == 0.0, 1.0, l)                      # all-dead lane
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_paged_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -284,9 +357,15 @@ def decode_attention_paged_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     q: (B, H, D); k_pages/v_pages: (P, Hkv, ps, D); block_tables: (B, T)
     int32 physical page ids in logical order; kv_lengths: (B,) int32.
-    One grid step streams one page; the table walk is clamped to the
-    last live page, so HBM reads scale with the live context at page
-    granularity.
+
+    One grid step per lane.  The pools stay in HBM; the kernel walks the
+    lane's ``ceil(len/ps)`` live table slots in groups of whole pages,
+    double-buffered, each page one copy of all its KV heads.  Every
+    query head of a KV head is scored at once, as (Hkv, H/Hkv, ps) per
+    page, into one online-softmax state per query head (float32 scores,
+    max, sum and accumulator).  A lane of length 0 fetches nothing and
+    returns zeros.  Pages per group: the fewest whose K and V bytes
+    reach 512 KiB, at most the table width (:func:`_pages_per_group`).
     """
     b, h, d = q.shape
     _, hkv, ps, _ = k_pages.shape
@@ -294,38 +373,36 @@ def decode_attention_paged_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert h % hkv == 0
     group = h // hkv
     scale = float(scale if scale is not None else d ** -0.5)
-    kernel = functools.partial(_paged_kernel, scale=scale, ps=ps)
-    q4 = q[:, :, None, :]
-
-    def kv_index(bb, hh, j, lens_ref, bt_ref):
-        jj = jnp.minimum(j, _last_live_block(lens_ref, bb, ps))
-        return (bt_ref[bb, jj], hh // group, 0, 0)
-
+    g = _pages_per_group(hkv, ps, d, k_pages.dtype.itemsize, t)
+    kernel = functools.partial(_paged_kernel, scale=scale, ps=ps,
+                               pages_per_group=g)
+    lane = pl.BlockSpec((1, hkv, group, d),
+                        lambda bb, lens_ref, bt_ref: (bb, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, t),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d),
-                         lambda bb, hh, j, lens_ref, bt_ref: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, ps, d), kv_index),
-            pl.BlockSpec((1, 1, ps, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, d),
-            lambda bb, hh, j, lens_ref, bt_ref: (bb, hh, 0, 0)),
+        grid=(b,),
+        in_specs=[lane, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=lane,
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((2, g, hkv, ps, d), k_pages.dtype),
+            pltpu.VMEM((2, g, hkv, ps, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hkv, group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(kv_lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q4, k_pages, v_pages)[:, :, 0, :]
+      q.reshape(b, hkv, group, d), k_pages, v_pages)
+    return out.reshape(b, h, d)
 
 
 def _paged_q8_kernel(len_ref, bt_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,

@@ -41,8 +41,8 @@ def decode_attention_q8(q, k_q, k_scale, v_q, v_scale, kv_lengths, *,
 def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_lengths):
     """Block-table decode attention over a global page pool.
 
-    On TPU the Pallas kernel walks the table, clamped to the last live
-    page; elsewhere the jnp oracle gathers the pages first.
+    On TPU the Pallas kernel walks each lane's live pages only;
+    elsewhere the jnp oracle gathers the pages first.
     """
     return jax.lax.platform_dependent(
         q, k_pages, v_pages, block_tables, kv_lengths,

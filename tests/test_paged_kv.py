@@ -48,15 +48,30 @@ def _shuffled_tables(b, t, n_pages, seed=0):
     return jnp.asarray(perm.reshape(b, t).astype(np.int32))
 
 
-@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2), (4, 1)])
-def test_paged_kernel_matches_ref_ragged(h, hkv):
-    b, d, ps, t, n_pages = 5, 32, 32, 8, 48
+@pytest.mark.parametrize("h,hkv,d,ps,pool_dtype", [
+    pytest.param(4, 4, 32, 32, jnp.float32, id="4-4"),
+    pytest.param(8, 2, 32, 32, jnp.float32, id="8-2"),
+    pytest.param(4, 1, 32, 32, jnp.float32, id="4-1"),
+    # the served shapes: olmo-1b MHA 16:16, qwen2.5-1.5b GQA 12:2
+    pytest.param(16, 16, 128, 64, jnp.bfloat16, id="olmo-ps64-bf16"),
+    pytest.param(16, 16, 128, 64, jnp.float32, id="olmo-ps64-f32"),
+    pytest.param(12, 2, 128, 64, jnp.bfloat16, id="qwen-ps64-bf16"),
+    pytest.param(12, 2, 128, 64, jnp.float32, id="qwen-ps64-f32"),
+    pytest.param(12, 2, 128, 16, jnp.bfloat16, id="qwen-ps16-bf16"),
+    pytest.param(12, 2, 128, 16, jnp.float32, id="qwen-ps16-f32"),
+])
+def test_paged_kernel_matches_ref_ragged(h, hkv, d, ps, pool_dtype):
+    b, t = 6, 8
+    n_pages = b * t + 1                    # the last one is the scratch page
     q = jax.random.normal(jax.random.PRNGKey(0), (b, h, d))
-    kp = jax.random.normal(jax.random.PRNGKey(1), (n_pages, hkv, ps, d))
-    vp = jax.random.normal(jax.random.PRNGKey(2), (n_pages, hkv, ps, d))
-    bt = _shuffled_tables(b, t, n_pages)
-    # ragged: dead lane, sub-page, page-aligned, partial, full
-    lens = jnp.array([0, 7, 64, 130, 256], jnp.int32)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (n_pages, hkv, ps, d),
+                           pool_dtype)
+    vp = jax.random.normal(jax.random.PRNGKey(2), (n_pages, hkv, ps, d),
+                           pool_dtype)
+    bt = _shuffled_tables(b, t, n_pages - 1)
+    bt = bt.at[0].set(n_pages - 1)         # the dead lane maps the scratch page
+    # ragged: dead lane, one key, sub-page, page-aligned, partial, full
+    lens = jnp.array([0, 1, 7, 2 * ps, 4 * ps + 2, t * ps], jnp.int32)
     out = decode_attention_paged_pallas(q, kp, vp, bt, lens,
                                         interpret=True)
     ref = decode_attention_paged_ref(q, kp, vp, bt, lens)

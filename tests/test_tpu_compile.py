@@ -3,8 +3,9 @@
 No chip is attached: the TPU compiler compiles for a chip that is only
 described (``v5e:2x2``), which refuses what interpret mode accepts
 (misaligned tiles, too much VMEM, a program too large for 16 GB).  All
-shapes are the full published widths of qwen2.5-1.5b.  Nothing runs, so
-these tests say nothing about results or times.
+shapes are the full published widths of qwen2.5-1.5b, and the paged
+kernel also at the shape olmo-1b is served at.  Nothing runs, so these
+tests say nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -30,6 +31,10 @@ LANES, PAGE, POSITIONS = 8, 16, 4096
 TABLE = POSITIONS // PAGE
 N_PAGES = LANES * TABLE + 1              # + the engine's scratch page
 HBM_BYTES = 16 * 10**9
+# olmo-1b as the benchmark serves it: 32 lanes of 2048 positions in
+# 64-token pages, 420 pages + the scratch page, MHA 16:16
+OLMO = get_config("olmo-1b")
+OLMO_LANES, OLMO_PAGE, OLMO_TABLE, OLMO_PAGES = 32, 64, 32, 421
 
 
 @pytest.fixture(scope="module")
@@ -61,16 +66,27 @@ def _on(sharding, tree):
         lambda x: _sds(sharding, x.shape, x.dtype), tree)
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16_pool", "f32_pool"])
-def test_paged_decode_kernel_compiles(one_chip, pool_dtype):
+_SHAPES = {  # lanes, table width, pages, page size, config
+    "qwen": (LANES, TABLE, N_PAGES, PAGE, CFG),
+    "olmo": (OLMO_LANES, OLMO_TABLE, OLMO_PAGES, OLMO_PAGE, OLMO),
+}
+
+
+@pytest.mark.parametrize("model,pool_dtype", [
+    pytest.param("qwen", jnp.bfloat16, id="bf16_pool"),
+    pytest.param("qwen", jnp.float32, id="f32_pool"),
+    pytest.param("olmo", jnp.bfloat16, id="olmo-bf16_pool"),
+    pytest.param("olmo", jnp.float32, id="olmo-f32_pool"),
+])
+def test_paged_decode_kernel_compiles(one_chip, model, pool_dtype):
     # the f32 pool is what the int8-KV model path hands the kernel
-    h, hkv, d = CFG.n_heads, CFG.n_kv_heads, CFG.hd
-    pool = _sds(one_chip, (N_PAGES, hkv, PAGE, d), pool_dtype)
+    lanes, table, n_pages, page, cfg = _SHAPES[model]
+    h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    pool = _sds(one_chip, (n_pages, hkv, page, d), pool_dtype)
     compiled = decode_attention_paged.lower(
-        _sds(one_chip, (LANES, h, d), jnp.bfloat16), pool, pool,
-        _sds(one_chip, (LANES, TABLE), jnp.int32),
-        _sds(one_chip, (LANES,), jnp.int32)).compile()
+        _sds(one_chip, (lanes, h, d), jnp.bfloat16), pool, pool,
+        _sds(one_chip, (lanes, table), jnp.int32),
+        _sds(one_chip, (lanes,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
