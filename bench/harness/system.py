@@ -5,6 +5,7 @@ imports the program; everything else takes what it measures from here.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
@@ -19,13 +20,15 @@ from repro.launch.compile_cache import setup_compilation_cache  # noqa: E402,F40
 from repro.models.common import ModelConfig  # noqa: E402
 from repro.serving import Request, ServeEngine  # noqa: E402,F401
 
-#: engine counters the harness reads (registry names under the engine's
-#: own prefix)
-COUNTERS = ("decode.steps", "tokens.generated", "prefix.tokens_matched",
-            "prefix.hits", "prefix.misses", "prefix.cow_copies")
-
 
 def program_config(arch: Arch) -> ModelConfig:
+    """The program's ``ModelConfig`` for ``arch``.  The configuration's
+    norm epsilon goes in as ``norm_eps`` where ``ModelConfig`` has a
+    field of that name; a program without one gets the config it always
+    got, and applies its own epsilon."""
+    extra = {}
+    if "norm_eps" in {f.name for f in dataclasses.fields(ModelConfig)}:
+        extra["norm_eps"] = arch.norm_eps
     return ModelConfig(
         name=arch.name, family="dense", n_layers=arch.n_layers,
         d_model=arch.d_model, n_heads=arch.n_heads,
@@ -34,7 +37,7 @@ def program_config(arch: Arch) -> ModelConfig:
         qkv_bias=arch.qkv_bias, norm=arch.norm,
         tie_embeddings=arch.tie_embeddings, rope_theta=arch.rope_theta,
         max_seq_len=arch.max_position_embeddings,
-        dtype=arch.compute_dtype)
+        dtype=arch.compute_dtype, **extra)
 
 
 def build_engine(arch: Arch, params) -> ServeEngine:
@@ -48,10 +51,14 @@ def build_engine(arch: Arch, params) -> ServeEngine:
 
 
 def counters(engine: ServeEngine) -> dict:
-    """The engine's registry counters, by their names without the
-    engine's prefix."""
-    return {c: engine.registry[f"{engine.name}.{c}"].value
-            for c in COUNTERS}
+    """Every counter and gauge in the engine's registry under its name
+    (``<engine.name>.``), keyed without that prefix; histograms, which
+    hold no single value, are left out.  A reader looks up the names it
+    needs and finds nothing under a name the program does not keep."""
+    prefix = f"{engine.name}."
+    reg = engine.registry
+    return {n[len(prefix):]: reg[n].value for n in reg.names()
+            if n.startswith(prefix) and reg[n].kind != "histogram"}
 
 
 def next_token_array(engine: ServeEngine):

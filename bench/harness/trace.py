@@ -8,8 +8,9 @@ covers its body); a Pallas kernel is an op whose text names
 ``custom_call_target="tpu_custom_call"``.  The plane ``/host:CPU`` holds
 the host threads; the benchmark's own ``TraceAnnotation`` spans
 (``bench.admit``, ``bench.dispatch``, ``bench.wait``) are on the Python
-thread.  Device and host events share one clock: nanoseconds from the
-start of the trace.
+thread, and so are the engine's (``serve.decode.prepare`` and the
+rest), nested inside them.  Device and host events share one clock:
+nanoseconds from the start of the trace.
 
 Which program plays which part is data: ``bench/programs.json`` maps a
 role (``decode`` and so on) to the program names that fill it.
@@ -30,7 +31,8 @@ PROGRAMS_FILE = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "programs.json")
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
-HOST_MARK = "bench."
+#: host annotations kept: the harness's own and the engine's spans
+HOST_MARK = ("bench.", "serve.")
 _FINGERPRINT = re.compile(r"\(\d+\)$")
 
 Interval = Tuple[float, float]          # seconds on the trace's clock
@@ -41,7 +43,7 @@ class Reduced:
     """One chip's device work and the host's annotations, in seconds."""
     modules: List[Tuple[str, float, float]]     # (program, start, end)
     kernels: List[Tuple[str, float, float]]     # Pallas kernel calls
-    host: List[Tuple[str, float, float]]        # bench.* annotations
+    host: List[Tuple[str, float, float]]        # by start; HOST_MARK
     n_devices: int
 
     def program_time(self, names, span: Interval) -> float:
@@ -161,8 +163,9 @@ def top_programs(reduced: Reduced, span: Interval, roles, k: int = 10):
 
 def idle_gaps(reduced: Reduced, span: Interval, k: int = 10):
     """[[host activity, seconds], ...]: device idle time inside ``span``
-    by what the host was doing (the ``bench.*`` annotation around the
-    middle of each gap), most first."""
+    by what the host was doing (the innermost annotation around the
+    middle of each gap: ``host`` is sorted by start, so the last that
+    covers it), most first."""
     ivs = merge([(s, e) for _, s, e in reduced.modules])
     gaps, t = [], span[0]
     for s, e in ivs:
