@@ -6,6 +6,19 @@ stacked on a leading axis) and in the type the engine serves them in.
 The plain reference reads the same arrays; both sides get them from the
 benchmark, neither makes its own.
 
+A ``deepseek_v3`` configuration is laid out as the engine is to take
+it: ``dense_blocks`` stacked over the ``first_k_dense_replace`` leading
+dense layers, ``blocks`` over the expert layers that follow.  Each
+layer's ``attn`` holds the latent attention, ``wq (d, H (dn+dr))``,
+``wkv_a (d, r+dr)``, ``kv_norm.scale (r)``, ``wkv_b (r, H (dn+dv))`` and
+``wo (H dv, d)``; an expert layer's ``moe`` is named as
+``repro.models.moe.init_moe`` names it: ``router (d, E)`` over all
+``E`` published experts, ``router_bias (E)`` (added to the scores for
+the choice of experts only), ``w_gate``/``w_up (held, d, f)`` and
+``w_down (held, f, d)`` for the experts held here, in the order of
+their ids, and ``shared`` (``w_gate``/``w_up``/``w_down``) at width
+``n_shared_experts f``.
+
 Scales: matrices are normal with standard deviation ``fan_in ** -0.5``,
 so every layer adds an O(1) update to the residual stream and attention
 scores are O(1) (a model whose layers barely move the stream would
@@ -31,6 +44,8 @@ def jax_key(seed: int) -> jax.Array:
 
 def param_shapes(arch: Arch) -> dict:
     """Leaf shapes of the served parameter tree, and each leaf's kind."""
+    if arch.mla is not None:
+        return _latent_expert_shapes(arch)
     d, L, hd = arch.d_model, arch.n_layers, arch.head_dim
     qd, kvd, ff = arch.n_heads * hd, arch.n_kv_heads * hd, arch.d_ff
     blocks = {
@@ -52,6 +67,42 @@ def param_shapes(arch: Arch) -> dict:
     if not arch.tie_embeddings:
         embed["head"] = ((d, arch.padded_vocab), "w")
     return {"embed": embed, "blocks": blocks, "final_norm": final_norm}
+
+
+def _latent_expert_shapes(arch: Arch) -> dict:
+    d, H, m, e = arch.d_model, arch.n_heads, arch.mla, arch.moe
+    r, dn, dr, dv = (m.kv_lora_rank, m.qk_nope_head_dim,
+                     m.qk_rope_head_dim, m.v_head_dim)
+    f, held, E = e.moe_intermediate_size, e.experts_held, e.router_experts
+
+    def swiglu(n, ff):
+        return {"w_gate": ((n, d, ff), "w"), "w_up": ((n, d, ff), "w"),
+                "w_down": ((n, ff, d), "w")}
+
+    def layers(n, **ffn):
+        return {"attn": {"wq": ((n, d, H * (dn + dr)), "w"),
+                         "wkv_a": ((n, d, r + dr), "w"),
+                         "kv_norm": {"scale": ((n, r), "scale")},
+                         "wkv_b": ((n, r, H * (dn + dv)), "w"),
+                         "wo": ((n, H * dv, d), "w")},
+                "norm1": {"scale": ((n, d), "scale")},
+                "norm2": {"scale": ((n, d), "scale")}, **ffn}
+
+    n_dense = e.first_k_dense_replace
+    n = arch.n_layers - n_dense
+    moe = {"router": ((n, d, E), "w"), "router_bias": ((n, E), "bias"),
+           "w_gate": ((n, held, d, f), "w"), "w_up": ((n, held, d, f), "w"),
+           "w_down": ((n, held, f, d), "w"),
+           "shared": swiglu(n, e.n_shared_experts * f)}
+    embed = {"tok": ((arch.padded_vocab, d), "embed")}
+    if not arch.tie_embeddings:
+        embed["head"] = ((d, arch.padded_vocab), "w")
+    out = {"embed": embed, "blocks": layers(n, moe=moe),
+           "final_norm": {"scale": ((d,), "scale")}}
+    if n_dense:
+        out["dense_blocks"] = layers(n_dense,
+                                     mlp=swiglu(n_dense, arch.d_ff))
+    return out
 
 
 def _leaf(key, shape, kind, init_range, dtype):
