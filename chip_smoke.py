@@ -115,36 +115,49 @@ def device_phase(chips: int = 1):
 
 
 def kernel_parity_phase() -> float:
-    """Paged decode op vs the f32 jnp reference at qwen2.5-1.5b widths.
-    Returns the largest abs error over both pool dtypes."""
+    """Paged decode op vs the f32 jnp reference at qwen2.5-1.5b widths,
+    on one layer of a two-layer stacked pool: the new token's row is
+    written in place and attended.  Returns the largest abs error over
+    both pool dtypes."""
     cfg = get_config("qwen2.5-1.5b")
     h, hkv, d, ps = cfg.n_heads, cfg.n_kv_heads, cfg.hd, 16
-    b, t = 8, 37                         # served lanes, 592 positions
+    b, t, n_layers, layer = 8, 37, 2, 1  # served lanes, 592 positions
     n_pages = b * t + 23
     rng = np.random.default_rng(0)
     # shuffled, disjoint page sets: physical naming must not matter
     bt = jnp.asarray(rng.permutation(n_pages)[:b * t].reshape(b, t),
                      jnp.int32)
-    # dead lane, one key, sub-page, page edges, mid, full table
-    lens = jnp.asarray([0, 1, 15, 16, 17, 255, 300, t * ps], jnp.int32)
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    # dead lane (its new key only), one key, sub-page, page edges, mid,
+    # full table; the new token is each lane's last key
+    lens = jnp.asarray([1, 1, 15, 16, 17, 255, 300, t * ps], jnp.int32)
+    pos = lens - 1
+    kq, kk, kv, kn = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(kq, (b, h, d), jnp.bfloat16)
+    k_new, v_new = jax.random.normal(kn, (2, b, hkv, d), jnp.bfloat16)
     worst = 0.0
     for pool_dtype in (jnp.bfloat16, jnp.float32):
-        k = jax.random.normal(kk, (n_pages, hkv, ps, d), pool_dtype)
-        v = jax.random.normal(kv, (n_pages, hkv, ps, d), pool_dtype)
-        out = decode_attention_paged(q, k, v, bt, lens)
+        shape = (n_layers, n_pages, hkv, ps, d)
+        k = jax.random.normal(kk, shape, pool_dtype)
+        v = jax.random.normal(kv, shape, pool_dtype)
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(decode_attention_paged_ref)(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32), bt, lens)
+            ref, k_ref, v_ref = jax.jit(decode_attention_paged_ref)(
+                q.astype(jnp.float32), k_new, v_new, k.astype(jnp.float32),
+                v.astype(jnp.float32), bt, lens, pos, layer)
+        out, k_out, v_out = decode_attention_paged(q, k_new, v_new, k, v,
+                                                   bt, lens, pos, layer)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
         name = jnp.dtype(pool_dtype).name
         print(f"kernel parity ({name} pool): max abs error {err!r}")
         check(math.isfinite(err) and err <= PARITY_ATOL,
               f"paged decode matches the reference within {PARITY_ATOL} "
               f"({name} pool)")
-        check(bool(jnp.all(out[0] == 0)), f"dead lane is zero ({name} pool)")
+        check(bool(jnp.array_equal(k_out.astype(jnp.float32), k_ref)
+                   and jnp.array_equal(v_out.astype(jnp.float32), v_ref)),
+              f"the new rows are written in place, nothing else "
+              f"({name} pool)")
+        dead = jnp.repeat(v_new[0].astype(jnp.float32), h // hkv, axis=0)
+        check(float(jnp.max(jnp.abs(out[0].astype(jnp.float32) - dead)))
+              <= PARITY_ATOL, f"dead lane reads its new value ({name} pool)")
         worst = max(worst, err)
     return worst
 

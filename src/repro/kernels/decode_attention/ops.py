@@ -38,26 +38,29 @@ def decode_attention_q8(q, k_q, k_scale, v_q, v_scale, kv_lengths, *,
 
 
 @jax.jit
-def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_lengths):
-    """Block-table decode attention over a global page pool.
+def decode_attention_paged(q, k, v, k_pages, v_pages, block_tables,
+                           kv_lengths, positions, layer):
+    """Block-table decode attention over one layer of the stacked page
+    pool, after writing the new token's key and value into it.
 
-    On TPU the Pallas kernel walks each lane's live pages only;
-    elsewhere the jnp oracle gathers the pages first.
+    Returns ``(out, k_pages, v_pages)``.  On TPU the Pallas kernel
+    writes the row in place and walks each lane's live pages only;
+    elsewhere the jnp oracle scatters the row and gathers the pages.
     """
     return jax.lax.platform_dependent(
-        q, k_pages, v_pages, block_tables, kv_lengths,
-        tpu=decode_attention_paged_pallas,
+        q, k, v, k_pages, v_pages, block_tables, kv_lengths, positions,
+        layer, tpu=decode_attention_paged_pallas,
         default=decode_attention_paged_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("qblock",))
-def decode_attention_paged_q8(q, k_pages, k_scale_pages, v_pages,
-                              v_scale_pages, block_tables, kv_lengths, *,
-                              qblock: int = 32):
+@jax.jit
+def decode_attention_paged_q8(q, k, k_scale, v, v_scale, k_pages,
+                              k_scale_pages, v_pages, v_scale_pages,
+                              block_tables, kv_lengths, positions, layer):
+    """The int8-KV form of :func:`decode_attention_paged`: per-(token,
+    head) scale pools beside the int8 value pools."""
     return jax.lax.platform_dependent(
-        q, k_pages, k_scale_pages, v_pages, v_scale_pages, block_tables,
-        kv_lengths,
-        tpu=functools.partial(decode_attention_paged_q8_pallas,
-                              qblock=qblock),
-        default=functools.partial(decode_attention_paged_q8_ref,
-                                  qblock=qblock))
+        q, k, k_scale, v, v_scale, k_pages, k_scale_pages, v_pages,
+        v_scale_pages, block_tables, kv_lengths, positions, layer,
+        tpu=decode_attention_paged_q8_pallas,
+        default=decode_attention_paged_q8_ref)

@@ -58,35 +58,70 @@ def decode_attention_q8_ref(q, k_q, k_scale, v_q, v_scale, kv_lengths, *,
 # paged (block-table) oracles
 # ----------------------------------------------------------------------
 
-def gather_pages(pages: jnp.ndarray, block_tables: jnp.ndarray
-                 ) -> jnp.ndarray:
-    """Materialize each lane's logical KV view from the page pool.
+def gather_pages(pages: jnp.ndarray, block_tables: jnp.ndarray,
+                 layer) -> jnp.ndarray:
+    """Materialize each lane's logical KV view from one layer of the
+    stacked page pool.
 
-    pages: (P, Hkv, ps, D); block_tables: (B, T) int32 physical page ids
-    in logical order -> (B, Hkv, T*ps, D).  Because the table lists the
-    lane's pages in logical order, the gathered array holds exactly the
-    values a dense per-lane cache would -- the paged-vs-dense parity
-    tests lean on this being an identity up to page naming.
+    pages: (L, P, Hkv, ps, ...) -- values (..., D) or per-token scales
+    (no trailing axis); block_tables: (B, T) int32 physical page ids in
+    logical order -> (B, Hkv, T*ps, ...).  The layer is indexed together
+    with the pages, so only the lanes' pages of that layer are read.
+    Because the table lists the lane's pages in logical order, the
+    gathered array holds exactly the values a dense per-lane cache
+    would -- the paged-vs-dense parity tests lean on this being an
+    identity up to page naming.
     """
-    g = pages[block_tables]                    # (B, T, Hkv, ps, D)
-    b, t, hkv, ps, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, t * ps, d)
+    g = pages[layer, block_tables]             # (B, T, Hkv, ps, ...)
+    b, t, hkv, ps = g.shape[:4]
+    return jnp.swapaxes(g, 1, 2).reshape((b, hkv, t * ps) + g.shape[4:])
 
 
-def decode_attention_paged_ref(q, k_pages, v_pages, block_tables,
-                               kv_lengths, *, scale=None):
-    """q: (B, H, D); pools (P, Hkv, ps, D); block_tables (B, T)."""
-    k = gather_pages(k_pages, block_tables)
-    v = gather_pages(v_pages, block_tables)
-    return decode_attention_ref(q, k, v, kv_lengths, scale=scale)
+def write_token(pages: jnp.ndarray, new: jnp.ndarray,
+                block_tables: jnp.ndarray, positions: jnp.ndarray,
+                layer) -> jnp.ndarray:
+    """Set each lane's new row (B, Hkv, ...) at its table position in
+    one layer of the stacked pool (L, P, Hkv, ps, ...).  Distinct lanes
+    own distinct pages (the allocator's invariant), so the rows never
+    collide."""
+    ps = pages.shape[3]
+    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None],
+                               axis=1)[:, 0]
+    return pages.at[layer, page, :, positions % ps].set(
+        new.astype(pages.dtype))
 
 
-def decode_attention_paged_q8_ref(q, k_pages, k_scale_pages, v_pages,
-                                  v_scale_pages, block_tables, kv_lengths,
-                                  *, scale=None, qblock: int = 32):
-    """Paged q8 oracle; scale pools are (P, Hkv, ps/qblock, 1)."""
-    k = dequant_kv_q8(gather_pages(k_pages, block_tables),
-                      gather_pages(k_scale_pages, block_tables), qblock)
-    v = dequant_kv_q8(gather_pages(v_pages, block_tables),
-                      gather_pages(v_scale_pages, block_tables), qblock)
-    return decode_attention_ref(q, k, v, kv_lengths, scale=scale)
+def decode_attention_paged_ref(q, k, v, k_pages, v_pages, block_tables,
+                               kv_lengths, positions, layer, *,
+                               scale=None):
+    """q: (B, H, D); new rows k/v (B, Hkv, D); pools (L, P, Hkv, ps, D);
+    block_tables (B, T) -> (out, k_pages, v_pages)."""
+    k_pages = write_token(k_pages, k, block_tables, positions, layer)
+    v_pages = write_token(v_pages, v, block_tables, positions, layer)
+    out = decode_attention_ref(q, gather_pages(k_pages, block_tables, layer),
+                               gather_pages(v_pages, block_tables, layer),
+                               kv_lengths, scale=scale)
+    return out, k_pages, v_pages
+
+
+def decode_attention_paged_q8_ref(q, k, k_scale, v, v_scale, k_pages,
+                                  k_scale_pages, v_pages, v_scale_pages,
+                                  block_tables, kv_lengths, positions,
+                                  layer, *, scale=None):
+    """Paged int8 oracle: int8 pools (L, P, Hkv, ps, D) with per-(token,
+    head) float32 scale pools (L, P, Hkv, ps); new rows k/v (B, Hkv, D)
+    int8 and scales (B, Hkv).  Returns (out, k_pages, k_scale_pages,
+    v_pages, v_scale_pages)."""
+    pools = tuple(
+        write_token(pool, new, block_tables, positions, layer)
+        for pool, new in ((k_pages, k), (k_scale_pages, k_scale),
+                          (v_pages, v), (v_scale_pages, v_scale)))
+
+    def dequant(vals, scales):
+        return (gather_pages(vals, block_tables, layer).astype(jnp.float32)
+                * gather_pages(scales, block_tables, layer)[..., None])
+
+    out = decode_attention_ref(q, dequant(pools[0], pools[1]),
+                               dequant(pools[2], pools[3]), kv_lengths,
+                               scale=scale)
+    return (out, *pools)

@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.ops import (decode_attention,
-                                                decode_attention_paged)
+                                                decode_attention_paged,
+                                                decode_attention_paged_q8)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.parallel.sharding import constrain
 from repro.models.common import (ModelConfig, apply_rope, dense_init,
@@ -140,20 +141,23 @@ def attention_decode(p, x: jnp.ndarray, cfg: ModelConfig, k_cache, v_cache,
     return out, k_cache, v_cache
 
 
-def attention_decode_paged(p, x: jnp.ndarray, cfg: ModelConfig, k_pages,
-                           v_pages, block_tables, cache_len,
-                           k_scale_pages=None, v_scale_pages=None):
-    """Single-token decode against a paged KV cache.
+def attention_decode_paged(p, x: jnp.ndarray, cfg: ModelConfig, pools,
+                           block_tables, cache_len, layer):
+    """Single-token decode against one layer of the stacked paged cache.
 
-    x: (B, 1, d); k_pages/v_pages: (P, Hkv, ps, D) -- one layer's slice
-    of the global page pool; block_tables: (B, T) physical page ids in
+    x: (B, 1, d); pools: ``{"k_pages", "v_pages"}`` (L, P, Hkv, ps, D),
+    the whole stack over layers, and ``layer`` the int32 index this
+    block reads and writes; block_tables: (B, T) physical page ids in
     logical order.  The lane's capacity is ``T*ps`` positions: a
     sliding-window lane owns a FIXED set of pages and rotates through
     them at page granularity (slot = position mod T*ps), so the ring
     write and the block-table gather share one formula with the
-    full-context case.  With ``cfg.kv_quant == "int8"`` the pools are
-    int8 with per-token f32 scale pools (P, Hkv, ps, 1), dequantized at
-    the attention read exactly like the dense cache.
+    full-context case.  With ``cfg.kv_quant == "int8"`` the value pools
+    are int8 beside per-(token, head) f32 scale pools
+    ``{"k_scale_pages", "v_scale_pages"}`` (L, P, Hkv, ps), quantized
+    exactly like the dense int8 cache.  The decode op writes the new
+    key and value into the pools in place and attends over them;
+    returns (out, pools).
     """
     b = x.shape[0]
     q, k, v = _project_qkv(p, x, cfg)
@@ -161,39 +165,24 @@ def attention_decode_paged(p, x: jnp.ndarray, cfg: ModelConfig, k_pages,
     q = constrain(apply_rope(q, cos, sin)[:, 0], "batch", None, None)
     k = constrain(apply_rope(k, cos, sin)[:, 0], "batch", None, None)
     v = constrain(v[:, 0], "batch", None, None)
-    ps = k_pages.shape[2]
-    t = block_tables.shape[1]
-    cap = t * ps                         # positions the table can back
-    slot = cache_len % cap
-    page = jnp.take_along_axis(block_tables, (slot // ps)[:, None],
-                               axis=1)[:, 0]
-    off = slot % ps
-
-    def put(pool, val):
-        # distinct lanes own distinct pages (allocator invariant), so
-        # the batched scatter writes never collide
-        return pool.at[page, :, off].set(val.astype(pool.dtype))
-
-    quant = cfg.kv_quant == "int8"
-    if quant:
+    ps = pools["k_pages"].shape[3]
+    cap = block_tables.shape[1] * ps     # positions the table can back
+    slot = (cache_len % cap).astype(jnp.int32)
+    eff_len = jnp.minimum(cache_len + 1, cap).astype(jnp.int32)
+    bt = block_tables.astype(jnp.int32)
+    if cfg.kv_quant == "int8":
         kq, ks = quantize_kv_token(k)
         vq, vs = quantize_kv_token(v)
-        k_pages = put(k_pages, kq)
-        v_pages = put(v_pages, vq)
-        k_scale_pages = put(k_scale_pages, ks)
-        v_scale_pages = put(v_scale_pages, vs)
-        k_eff = k_pages.astype(jnp.float32) * k_scale_pages
-        v_eff = v_pages.astype(jnp.float32) * v_scale_pages
+        keys = ("k_pages", "k_scale_pages", "v_pages", "v_scale_pages")
+        out, *new = decode_attention_paged_q8(
+            q, kq, ks[..., 0], vq, vs[..., 0], *(pools[name] for name in keys),
+            bt, eff_len, slot, layer)
+        pools = dict(zip(keys, new))
     else:
-        k_pages = put(k_pages, k)
-        v_pages = put(v_pages, v)
-        k_eff, v_eff = k_pages, v_pages
-    eff_len = jnp.minimum(cache_len + 1, cap)
-    out = decode_attention_paged(q, k_eff, v_eff,
-                                 block_tables.astype(jnp.int32),
-                                 eff_len.astype(jnp.int32))
+        out, kp, vp = decode_attention_paged(
+            q, k, v, pools["k_pages"], pools["v_pages"], bt, eff_len, slot,
+            layer)
+        pools = {"k_pages": kp, "v_pages": vp}
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
-    if quant:
-        return out, k_pages, v_pages, k_scale_pages, v_scale_pages
-    return out, k_pages, v_pages
+    return out, pools

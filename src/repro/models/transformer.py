@@ -239,6 +239,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 #: metadata; ``block_tables`` names pool pages shared by every layer.
 CACHE_SHARED_KEYS = ("len", "block_tables")
 
+#: the paged cache's page pools: stacked per layer, but read and written
+#: in place by the decode op at a layer index, never sliced per layer
+PAGED_POOL_KEYS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+
 
 def paged_capacity(max_len: int, cfg: ModelConfig) -> int:
     """Positions one lane's block table must back: the window if the
@@ -261,8 +265,9 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     * ``block_tables``: ``(B, T)`` int32, lane's physical page ids in
       logical order (``T = capacity/ps``); rides the scan carry next to
       ``len``, un-sliced by the layer loop;
-    * int8 adds ``k_scale_pages``/``v_scale_pages`` ``(L, P, Hkv, ps, 1)``
-      per-token scales (same quantization as the dense int8 cache).
+    * int8 adds ``k_scale_pages``/``v_scale_pages`` ``(L, P, Hkv, ps)``
+      per-token scales (same quantization as the dense int8 cache, whose
+      scales keep a trailing unit axis).
 
     ``n_pages`` defaults to dense-equivalent capacity
     (``batch * T``); a SERVING caller passes fewer lanes' worth and
@@ -289,7 +294,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
         if cfg.kv_quant == "int8":
             cache["k_pages"] = jnp.zeros(kv_shape, jnp.int8)
             cache["v_pages"] = jnp.zeros(kv_shape, jnp.int8)
-            sc_shape = (L, n_pages, cfg.n_kv_heads, page_size, 1)
+            sc_shape = (L, n_pages, cfg.n_kv_heads, page_size)
             cache["k_scale_pages"] = jnp.ones(sc_shape, jnp.float32)
             cache["v_scale_pages"] = jnp.ones(sc_shape, jnp.float32)
         else:
@@ -305,21 +310,16 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
 
 
 def _attn_decode(p, h, cfg, layer_cache, cache_len, new_cache,
-                 attn_key="attn", block_tables=None):
-    """Run cached attention, handling the quantized-KV and paged layouts."""
+                 attn_key="attn", block_tables=None, layer=None):
+    """Run cached attention, handling the quantized-KV and paged layouts.
+    A paged ``layer_cache`` holds the whole stacked pools, which the
+    decode op reads and writes at ``layer``."""
     if block_tables is not None:
-        if cfg.kv_quant == "int8":
-            att, kp, vp, ks, vs = attention_decode_paged(
-                p[attn_key], h, cfg, layer_cache["k_pages"],
-                layer_cache["v_pages"], block_tables, cache_len,
-                layer_cache["k_scale_pages"], layer_cache["v_scale_pages"])
-            new_cache.update(k_pages=kp, v_pages=vp, k_scale_pages=ks,
-                             v_scale_pages=vs)
-        else:
-            att, kp, vp = attention_decode_paged(
-                p[attn_key], h, cfg, layer_cache["k_pages"],
-                layer_cache["v_pages"], block_tables, cache_len)
-            new_cache.update(k_pages=kp, v_pages=vp)
+        pools = {k: layer_cache[k] for k in PAGED_POOL_KEYS
+                 if k in layer_cache}
+        att, pools = attention_decode_paged(p[attn_key], h, cfg, pools,
+                                            block_tables, cache_len, layer)
+        new_cache.update(pools)
         return att
     if cfg.kv_quant == "int8":
         att, kc, vc, ks, vs = attention_decode(
@@ -335,12 +335,14 @@ def _attn_decode(p, h, cfg, layer_cache, cache_len, new_cache,
 
 
 def block_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-                 layer_cache: Params, cache_len,
-                 block_tables=None) -> Tuple[jnp.ndarray, Params]:
+                 layer_cache: Params, cache_len, block_tables=None,
+                 layer=None) -> Tuple[jnp.ndarray, Params]:
     """One-token decode through one block. x: (B, 1, d).
 
-    ``block_tables`` (B, T) selects the paged-attention path; the dense
-    per-lane cache path is the pinned parity reference.
+    ``block_tables`` (B, T) selects the paged-attention path, whose
+    pools in ``layer_cache`` are the whole stack and ``layer`` this
+    block's index in it; the dense per-lane cache path is the pinned
+    parity reference.
     """
     new_cache = dict(layer_cache)
     h = apply_norm(p["norm1"], x, cfg.norm)
@@ -352,7 +354,7 @@ def block_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         return x + y, new_cache
     if cfg.family == "hybrid":
         att = _attn_decode(p, h, cfg, layer_cache, cache_len, new_cache,
-                           block_tables=block_tables)
+                           block_tables=block_tables, layer=layer)
         ssm, st = mamba2_decode(p["ssm"], h, cfg,
                                 {"h": layer_cache["ssm_h"],
                                  "conv": layer_cache["ssm_conv"]})
@@ -361,7 +363,7 @@ def block_decode(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         h2 = apply_norm(p["norm2"], x, cfg.norm)
         return x + swiglu(p["mlp"], h2), new_cache
     att = _attn_decode(p, h, cfg, layer_cache, cache_len, new_cache,
-                       block_tables=block_tables)
+                       block_tables=block_tables, layer=layer)
     x = x + att
     h2 = apply_norm(p["norm2"], x, cfg.norm)
     if cfg.family == "moe":
@@ -379,36 +381,44 @@ def lm_decode_step(params: Params, cfg: ModelConfig, cache: Params,
     The stacked (L, ...) cache rides the scan CARRY (not xs/ys): XLA
     aliases while-loop carries in place, so the multi-GB KV cache is
     updated without the double buffering a scan-output cache would cost.
-    Each layer dynamic-slices its page out of the stack and writes the
-    new token back at its layer index.  A paged cache carries its
-    ``block_tables`` un-sliced next to ``len`` (the table is per-lane,
-    shared by every layer); everything else stacks as before.
+    The paged pools ride it whole: each layer's decode op reads and
+    writes them at the layer index, in the layout they arrive in, so no
+    layer of the pool is ever sliced out, copied or put back.  The dense
+    per-lane cache and recurrent state, small or the parity reference,
+    are dynamic-sliced per layer and written back at its index.  A paged
+    cache carries its ``block_tables`` un-sliced next to ``len`` (the
+    table is per-lane, shared by every layer).
     """
     x = embed(params["embed"], tokens[:, None], cfg.compute_dtype)
     cache_len = cache["len"]
     block_tables = cache.get("block_tables")
-    layer_keys = [k for k in cache if k not in CACHE_SHARED_KEYS]
-    stack = {k: cache[k] for k in layer_keys}
+    pools = {k: cache[k] for k in PAGED_POOL_KEYS if k in cache}
+    stack = {k: cache[k] for k in cache
+             if k not in CACHE_SHARED_KEYS and k not in pools}
 
     def body(carry, inp):
-        x, stack = carry
+        x, stack, pools = carry
         layer_params, i = inp
         layer_cache = {
             k: jax.lax.dynamic_index_in_dim(stack[k], i, 0, keepdims=False)
-            for k in layer_keys}
+            for k in stack}
+        layer_cache.update(pools)
         x, new_lc = block_decode(layer_params, x, cfg, layer_cache,
-                                 cache_len, block_tables=block_tables)
+                                 cache_len, block_tables=block_tables,
+                                 layer=i)
         stack = {
             k: jax.lax.dynamic_update_index_in_dim(stack[k], new_lc[k], i, 0)
-            for k in layer_keys}
-        return (x, stack), None
+            for k in stack}
+        pools = {k: new_lc[k] for k in pools}
+        return (x, stack, pools), None
 
-    (x, stack), _ = layer_scan(
-        body, (x, stack),
+    (x, stack, pools), _ = layer_scan(
+        body, (x, stack, pools),
         (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = lm_logits(params["embed"], x[:, 0], cfg)
     new_cache = dict(stack)
+    new_cache.update(pools)
     new_cache["len"] = cache_len + 1
     if block_tables is not None:
         new_cache["block_tables"] = block_tables

@@ -53,7 +53,8 @@ from repro.analysis.invariants import invariant
 from repro.analysis.sanitizer import PageSanitizer
 from repro.models.common import ModelConfig
 from repro.models.registry import Model, build_model
-from repro.models.transformer import (init_cache, init_paged_cache,
+from repro.models.transformer import (CACHE_SHARED_KEYS, PAGED_POOL_KEYS,
+                                      init_cache, init_paged_cache,
                                       lm_prefill_batched, paged_capacity,
                                       sample_tokens)
 from repro.obs.flight import FlightRecorder, flight_guard
@@ -454,12 +455,6 @@ def _bucket_len(n: int, floor: int = 8) -> int:
     return b
 
 
-#: cache keys holding the shared page pool (no lane axis)
-_POOL_KEYS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
-#: cache keys indexed by lane on axis 0 (everything else stacks (L, B, ...))
-_LANE0_KEYS = ("len", "block_tables")
-
-
 def prefix_sharing_supported(cfg: ModelConfig) -> bool:
     """Whether ``cfg`` can serve with ``prefix_sharing=True``: the whole
     prompt context must be page-resident and append-only.  Sliding-
@@ -670,11 +665,18 @@ class ServeEngine:
         self._decode = jax.jit(
             lambda p, c, t: self.model.decode_step(p, c, t))
         self._temperature = self.temperature      # captured, see above
+        # the per-layer state (page pools, dense KV, recurrent state) is
+        # donated: the dispatch updates it in place, with no copy of the
+        # pool on the way in or out, and the pre-dispatch arrays are
+        # deleted (every path reads ``self.cache`` afresh).  The per-lane
+        # vectors (``len``, ``block_tables``) travel as their own,
+        # undonated argument: a wrapper of the dispatch may hand them
+        # back as it was given them, as the benchmark's fault plants do
         self._decode_n = jax.jit(
             functools.partial(self._decode_n_fn,
                               temperature=self._temperature,
                               len_cap=self.max_len - 1),
-            static_argnames=("n_steps",))
+            static_argnames=("n_steps",), donate_argnames=("state",))
 
         def prefill_fn(p, tokens, last_pos):
             # Python side effect fires once per trace == once per shape
@@ -691,24 +693,41 @@ class ServeEngine:
 
         self._ssm_prefill = jax.jit(ssm_prefill_fn)
 
-    def _decode_n_fn(self, params, cache, tokens, rng, remaining,
+    def _decode_n_fn(self, params, cache, state, tokens, rng, remaining,
                      lane_seed, tok_idx, *, n_steps, temperature, len_cap):
+        """The decode dispatch over the cache split in two: ``cache``
+        the per-lane vectors (``len``, ``block_tables``), ``state`` the
+        donated per-layer state.  Returns (tokens, valid, next tokens,
+        cache, state, remaining, tok_idx)."""
         # Python side effect fires once per XLA trace == once per
         # distinct n_steps; the telemetry overhead-budget test pins this
         # counter traced-vs-untraced.
         self.stats["decode_compiles"] += 1
-        return self.model.decode_n_steps(
-            params, cache, tokens, rng, remaining, lane_seed, tok_idx,
-            n_steps=n_steps, temperature=temperature, len_cap=len_cap)
+        toks, valid, nxt, full, remaining, tok_idx = \
+            self.model.decode_n_steps(
+                params, {**cache, **state}, tokens, rng, remaining,
+                lane_seed, tok_idx, n_steps=n_steps,
+                temperature=temperature, len_cap=len_cap)
+        return (toks, valid, nxt, {k: full[k] for k in cache},
+                {k: full[k] for k in state}, remaining, tok_idx)
+
+    def _split_cache(self):
+        """(per-lane vectors, per-layer state) of ``self.cache``: the
+        dispatch's two cache arguments, the second one donated."""
+        lanes = {k: x for k, x in self.cache.items()
+                 if k in CACHE_SHARED_KEYS}
+        state = {k: x for k, x in self.cache.items()
+                 if k not in CACHE_SHARED_KEYS}
+        return lanes, state
 
     def decode_program_text(self) -> str:
         """Compiled text of the ``dispatch_n``-step decode dispatch at
         the live arguments: which kernels the compiler placed in it (a
         Pallas kernel shows as ``tpu_custom_call``)."""
         return self._decode_n.lower(
-            self.params, self.cache, self._next_token, self._rng_decode,
-            self._remaining, self._lane_seed, self._tok_idx,
-            n_steps=self.dispatch_n).compile().as_text()
+            self.params, *self._split_cache(), self._next_token,
+            self._rng_decode, self._remaining, self._lane_seed,
+            self._tok_idx, n_steps=self.dispatch_n).compile().as_text()
 
     # -- telemetry --------------------------------------------------------
     @property
@@ -963,7 +982,7 @@ class ServeEngine:
             new = self.pool.cow(old, holder=lane)
             self._lane_reserved[lane] -= 1
             self._lane_pages[lane][idx] = new
-            for key in _POOL_KEYS:
+            for key in PAGED_POOL_KEYS:
                 if key in self.cache:
                     self.cache[key] = self.cache[key].at[:, new].set(
                         self.cache[key][:, old])
@@ -1080,14 +1099,17 @@ class ServeEngine:
         self.cache["len"] = self.cache["len"].at[lane].set(plen)
         self._set_first_token(logits, lane)
 
-    def _prompt_kv_views(self, kv, plen: int, smax: int):
+    def _prompt_kv_views(self, kv, plen: int, smax: int,
+                         paged: bool = False):
         """Last ``min(plen, smax)`` prompt positions of the prefill KV,
         laid out at their ring slots (``slot = position mod smax``) and
         quantized when the cache is int8 (via ``quantize_kv_token``, the
         same per-(token, head) scales the decode write path uses).
 
         Returns (entries, take): ``entries`` maps cache key -> a
-        (L, Hkv, take[, pad], ...) array in the cache's dtype.
+        (L, Hkv, take, ...) array in the cache's dtype: the dense keys,
+        or with ``paged`` the page pools' keys in the pools' layout
+        (the only place the two layouts meet).
         """
         from repro.models.attention import quantize_kv_token
 
@@ -1103,11 +1125,17 @@ class ServeEngine:
             if shift:
                 k = jnp.roll(k, shift, axis=2)
                 v = jnp.roll(v, shift, axis=2)
+        entries = {"k": k, "v": v}
         if self.cfg.kv_quant == "int8":
             kq, ks = quantize_kv_token(k)
             vq, vs = quantize_kv_token(v)
-            return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, take
-        return {"k": k, "v": v}, take
+            entries = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        if paged:
+            # the pools keep a scale per (token, head) with no trailing
+            # unit axis
+            entries = {f"{key}_pages": val[..., 0] if key.endswith("_scale")
+                       else val for key, val in entries.items()}
+        return entries, take
 
     def _scatter_prompt_dense(self, kv, lane: int, plen: int) -> None:
         smax = self.cache["k"].shape[3]
@@ -1125,29 +1153,33 @@ class ServeEngine:
         backed by shared prefix pages: their bytes are the donor's, and
         writing them would corrupt every other lane mapping them."""
         ps = self.page_size
-        entries, take = self._prompt_kv_views(kv, plen, ps * self._bt_width)
+        entries, take = self._prompt_kv_views(kv, plen, ps * self._bt_width,
+                                              paged=True)
         n_pg = -(-take // ps)
         pad = n_pg * ps - take
         if pad:
-            entries = {key: jnp.pad(val, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            entries = {key: jnp.pad(val, [(0, pad) if ax == 2 else (0, 0)
+                                          for ax in range(val.ndim)])
                        for key, val in entries.items()}
-        key_map = {"k": "k_pages", "v": "v_pages",
-                   "k_scale": "k_scale_pages", "v_scale": "v_scale_pages"}
         for i, page in enumerate(self._lane_pages[lane][:n_pg]):
             if i < first_block:
                 continue
             for key, val in entries.items():
-                pk = key_map[key]
-                seg = val[:, None, :, i * ps:(i + 1) * ps]
-                self.cache[pk] = jax.lax.dynamic_update_slice(
-                    self.cache[pk], seg.astype(self.cache[pk].dtype),
-                    (0, page, 0, 0, 0))
+                self._write_page(key, page,
+                                 val[:, None, :, i * ps:(i + 1) * ps])
         s = self._sanitizer
         if s is not None:
             written = self._lane_pages[lane][first_block:n_pg]
             if written:
                 s.record("write", lane=lane, pages=list(written),
                          kind="prefill")
+
+    def _write_page(self, key: str, page: int, seg) -> None:
+        """Write ``seg`` (L, 1, ...), one page of every layer in the
+        pool's layout, into page ``page`` of pool ``key``."""
+        pool = self.cache[key]
+        self.cache[key] = jax.lax.dynamic_update_slice(
+            pool, seg.astype(pool.dtype), (0, page) + (0,) * (pool.ndim - 2))
 
     def _set_first_token(self, logits: jnp.ndarray, lane: int) -> None:
         key = jax.random.fold_in(self._rng_prefill, self._admit_count)
@@ -1161,9 +1193,9 @@ class ServeEngine:
         block-table row names its pages)."""
         out = {}
         for key, x in self.cache.items():
-            if key in _POOL_KEYS:
+            if key in PAGED_POOL_KEYS:
                 out[key] = x
-            elif key in _LANE0_KEYS:
+            elif key in CACHE_SHARED_KEYS:
                 out[key] = x[lane:lane + 1]
             else:
                 out[key] = x[:, lane:lane + 1]
@@ -1172,9 +1204,9 @@ class ServeEngine:
     def _merge_lane_cache(self, lane_cache: Dict[str, jnp.ndarray],
                           lane: int) -> None:
         for key, x in lane_cache.items():
-            if key in _POOL_KEYS:
+            if key in PAGED_POOL_KEYS:
                 self.cache[key] = x
-            elif key in _LANE0_KEYS:
+            elif key in CACHE_SHARED_KEYS:
                 self.cache[key] = self.cache[key].at[lane].set(x[0])
             else:
                 self.cache[key] = jax.lax.dynamic_update_slice(
@@ -1207,7 +1239,7 @@ class ServeEngine:
             new_logits, new_cache = self.model.decode_step(
                 params, cache, tok[None])
             cache = {
-                key: (new_cache[key] if key in _POOL_KEYS
+                key: (new_cache[key] if key in PAGED_POOL_KEYS
                       else jax.tree_util.tree_map(
                           lambda new, old: jnp.where(live, new, old),
                           new_cache[key], cache[key]))
@@ -1301,11 +1333,12 @@ class ServeEngine:
                         n * self.pool.n_reserved)
                 self.stats["decode_lane_steps"] += sum(steps)
             with self.tracer.span("decode.launch", track=self.name):
-                (toks, valid, self._next_token, self.cache,
+                (toks, valid, self._next_token, lanes, state,
                  self._remaining, self._tok_idx) = self._decode_n(
-                    self.params, self.cache, self._next_token,
+                    self.params, *self._split_cache(), self._next_token,
                     self._rng_decode, self._remaining, self._lane_seed,
                     self._tok_idx, n_steps=n)
+                self.cache = {**lanes, **state}
             self.stats["decode_dispatches"] += 1
             self.stats["decode_steps"] += n
             # one host transfer drains the whole block
@@ -1439,7 +1472,7 @@ class ServeEngine:
                 s.record("capture", lane=lane, pages=pages)
             idx = jnp.asarray(pages, jnp.int32)
             kv = {key: jnp.take(self.cache[key], idx, axis=1)
-                  for key in _POOL_KEYS if key in self.cache}
+                  for key in PAGED_POOL_KEYS if key in self.cache}
             ssm = {key: self.cache[key][:, lane]
                    for key in ("ssm_h", "ssm_conv") if key in self.cache}
             kv, ssm, nxt, seed, idx_t = jax.device_get(
@@ -1511,11 +1544,8 @@ class ServeEngine:
                 self._map_pages(lane, ckpt.n_pages)
                 for i, page in enumerate(self._lane_pages[lane]):
                     for key, val in ckpt.kv_pages.items():
-                        seg = jnp.asarray(val[:, i:i + 1])
-                        self.cache[key] = jax.lax.dynamic_update_slice(
-                            self.cache[key],
-                            seg.astype(self.cache[key].dtype),
-                            (0, page, 0, 0, 0))
+                        self._write_page(key, page,
+                                         jnp.asarray(val[:, i:i + 1]))
                 for key, val in ckpt.ssm_state.items():
                     self.cache[key] = self.cache[key].at[:, lane].set(
                         jnp.asarray(val))

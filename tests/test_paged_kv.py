@@ -27,8 +27,9 @@ from repro.kernels.decode_attention import (
     decode_attention_lengthaware_pallas, decode_attention_paged_pallas,
     decode_attention_paged_q8_pallas, decode_attention_paged_q8_ref,
     decode_attention_paged_ref, decode_attention_ref, gather_pages,
-    kv_pages_fetched, quantize_kv_q8)
+    kv_pages_fetched)
 from repro.models import build_model
+from repro.models.attention import quantize_kv_token
 from repro.models.transformer import (init_cache, init_paged_cache,
                                       lm_decode_step)
 from repro.serving import PagePool, Request, ServeEngine
@@ -48,6 +49,12 @@ def _shuffled_tables(b, t, n_pages, seed=0):
     return jnp.asarray(perm.reshape(b, t).astype(np.int32))
 
 
+def _new_rows(b, hkv, d, dtype, seed):
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.normal(kk, (b, hkv, d)).astype(dtype),
+            jax.random.normal(kv, (b, hkv, d)).astype(dtype))
+
+
 @pytest.mark.parametrize("h,hkv,d,ps,pool_dtype", [
     pytest.param(4, 4, 32, 32, jnp.float32, id="4-4"),
     pytest.param(8, 2, 32, 32, jnp.float32, id="8-2"),
@@ -61,43 +68,132 @@ def _shuffled_tables(b, t, n_pages, seed=0):
     pytest.param(12, 2, 128, 16, jnp.float32, id="qwen-ps16-f32"),
 ])
 def test_paged_kernel_matches_ref_ragged(h, hkv, d, ps, pool_dtype):
-    b, t = 6, 8
+    b, t, n_layers, layer = 6, 8, 2, 1
     n_pages = b * t + 1                    # the last one is the scratch page
     q = jax.random.normal(jax.random.PRNGKey(0), (b, h, d))
-    kp = jax.random.normal(jax.random.PRNGKey(1), (n_pages, hkv, ps, d),
-                           pool_dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (n_pages, hkv, ps, d),
-                           pool_dtype)
+    shape = (n_layers, n_pages, hkv, ps, d)
+    kp = jax.random.normal(jax.random.PRNGKey(1), shape, pool_dtype)
+    vp = jax.random.normal(jax.random.PRNGKey(2), shape, pool_dtype)
+    k, v = _new_rows(b, hkv, d, pool_dtype, seed=3)
     bt = _shuffled_tables(b, t, n_pages - 1)
     bt = bt.at[0].set(n_pages - 1)         # the dead lane maps the scratch page
-    # ragged: dead lane, one key, sub-page, page-aligned, partial, full
-    lens = jnp.array([0, 1, 7, 2 * ps, 4 * ps + 2, t * ps], jnp.int32)
-    out = decode_attention_paged_pallas(q, kp, vp, bt, lens,
-                                        interpret=True)
-    ref = decode_attention_paged_ref(q, kp, vp, bt, lens)
+    # ragged: dead lane, one key, sub-page, page-aligned, partial, full;
+    # the new token is each lane's last key
+    lens = jnp.array([1, 1, 7, 2 * ps, 4 * ps + 2, t * ps], jnp.int32)
+    pos = lens - 1
+    out, kw, vw = decode_attention_paged_pallas(
+        q, k, v, kp, vp, bt, lens, pos, layer, interpret=True)
+    ref, kr, vr = decode_attention_paged_ref(q, k, v, kp, vp, bt, lens, pos,
+                                             layer)
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
-    assert jnp.all(out[0] == 0.0)          # dead lane: no live keys
+    assert jnp.array_equal(kw, kr) and jnp.array_equal(vw, vr)
+    # dead lane: its only key is its own new one, so every query head of
+    # a KV head reads that head's new value
+    want = jnp.repeat(v[0].astype(jnp.float32), h // hkv, axis=0)
+    assert jnp.max(jnp.abs(out[0] - want)) < 2e-5
     # and against the pinned dense parity reference on the gathered view
-    gk, gv = gather_pages(kp, bt), gather_pages(vp, bt)
+    gk, gv = gather_pages(kw, bt, layer), gather_pages(vw, bt, layer)
     dense = decode_attention_lengthaware_pallas(q, gk, gv, lens, bk=ps,
                                                 interpret=True)
     assert jnp.max(jnp.abs(out - dense)) < 2e-5
 
 
+def _q8_pools(shape, seed):
+    """int8 value pool and its per-(token, head) scale pool, quantized
+    the way the model quantizes a token's KV."""
+    vals, scales = quantize_kv_token(
+        jax.random.normal(jax.random.PRNGKey(seed), shape))
+    return vals, scales[..., 0]
+
+
 def test_paged_q8_kernel_matches_ref():
-    b, h, hkv, d, ps, t, n_pages, qblock = 3, 4, 2, 32, 32, 4, 16, 8
+    b, h, hkv, d, ps, t, n_pages, layer = 3, 4, 2, 32, 32, 4, 16, 0
     q = jax.random.normal(jax.random.PRNGKey(0), (b, h, d))
-    k = jax.random.normal(jax.random.PRNGKey(1), (n_pages, hkv, ps, d))
-    v = jax.random.normal(jax.random.PRNGKey(2), (n_pages, hkv, ps, d))
-    kq, ks = quantize_kv_q8(k, qblock=qblock)
-    vq, vs = quantize_kv_q8(v, qblock=qblock)
+    kq, ks = _q8_pools((2, n_pages, hkv, ps, d), seed=1)
+    vq, vs = _q8_pools((2, n_pages, hkv, ps, d), seed=2)
+    k, v = _new_rows(b, hkv, d, jnp.float32, seed=3)
+    (nk, nks), (nv, nvs) = quantize_kv_token(k), quantize_kv_token(v)
     bt = _shuffled_tables(b, t, n_pages, seed=3)
-    lens = jnp.array([0, 50, 128], jnp.int32)
-    out = decode_attention_paged_q8_pallas(q, kq, ks, vq, vs, bt, lens,
-                                           qblock=qblock, interpret=True)
-    ref = decode_attention_paged_q8_ref(q, kq, ks, vq, vs, bt, lens,
-                                        qblock=qblock)
+    lens = jnp.array([1, 50, 128], jnp.int32)
+    pos = lens - 1
+    args = (q, nk, nks[..., 0], nv, nvs[..., 0], kq, ks, vq, vs, bt, lens,
+            pos, layer)
+    out, *pools = decode_attention_paged_q8_pallas(*args, interpret=True)
+    ref, *want = decode_attention_paged_q8_ref(*args)
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
+    assert all(jnp.array_equal(a, w) for a, w in zip(pools, want))
+
+
+def _per_layer_op(q, new, pools, bt, lens, pos, layer):
+    """The computation the stacked op replaced, written out on one
+    layer's pool: scatter the new rows into ``pool[layer]``, gather the
+    lanes' pages, dequantize, attend."""
+    ps = pools[0].shape[3]
+    page = jnp.take_along_axis(bt, (pos // ps)[:, None], axis=1)[:, 0]
+    layer_pools = [p[layer].at[page, :, pos % ps].set(n.astype(p.dtype))
+                   for p, n in zip(pools, new)]
+
+    def view(pool):
+        g = pool[bt]                                  # (B, T, Hkv, ps, ...)
+        g = jnp.swapaxes(g, 1, 2)
+        return g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
+
+    if len(pools) == 4:                               # int8 + scales
+        kk = view(layer_pools[0]).astype(jnp.float32) * view(
+            layer_pools[1])[..., None]
+        vv = view(layer_pools[2]).astype(jnp.float32) * view(
+            layer_pools[3])[..., None]
+    else:
+        kk, vv = view(layer_pools[0]), view(layer_pools[1])
+    out = decode_attention_ref(q, kk, vv, lens)
+    return out, [p.at[layer].set(lp) for p, lp in zip(pools, layer_pools)]
+
+
+@pytest.mark.parametrize("impl", ["oracle", "pallas"])
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("pool_dtype", ["bf16", "f32", "int8"])
+def test_stacked_pool_op_matches_per_layer(pool_dtype, layer, window, impl):
+    """The stacked-pool op (the oracle the CPU runs, the Pallas kernel in
+    interpret mode) equals the old per-layer scatter-gather-attend on
+    ``pool[layer]``, and leaves every other layer as it was.  ``window``:
+    the ring of a sliding-window lane has wrapped, so every table slot is
+    live and the new token lands in a middle page."""
+    b, h, hkv, d, ps, t, n_layers = 4, 8, 2, 128, 16, 4, 3
+    n_pages = b * t + 1
+    shape = (n_layers, n_pages, hkv, ps, d)
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, h, d))
+    bt = _shuffled_tables(b, t, n_pages - 1, seed=5)
+    cap = t * ps
+    if window:
+        ctx = jnp.array([cap + 5, 2 * cap + 17, cap + ps, 3 * cap - 1])
+        lens, pos = jnp.full((b,), cap, jnp.int32), ctx % cap
+    else:
+        lens = jnp.array([1, ps, ps + 3, cap], jnp.int32)
+        pos = lens - 1
+    pos = pos.astype(jnp.int32)
+    if pool_dtype == "int8":
+        kq, ks = _q8_pools(shape, seed=1)
+        vq, vs = _q8_pools(shape, seed=2)
+        k, v = _new_rows(b, hkv, d, jnp.float32, seed=3)
+        (nk, nks), (nv, nvs) = quantize_kv_token(k), quantize_kv_token(v)
+        new, pools = (nk, nks[..., 0], nv, nvs[..., 0]), (kq, ks, vq, vs)
+        fn = (decode_attention_paged_q8_pallas if impl == "pallas"
+              else decode_attention_paged_q8_ref)
+        args = new[:2] + new[2:] + pools
+    else:
+        dtype = jnp.bfloat16 if pool_dtype == "bf16" else jnp.float32
+        pools = (jax.random.normal(jax.random.PRNGKey(1), shape, dtype),
+                 jax.random.normal(jax.random.PRNGKey(2), shape, dtype))
+        new = _new_rows(b, hkv, d, dtype, seed=3)
+        fn = (decode_attention_paged_pallas if impl == "pallas"
+              else decode_attention_paged_ref)
+        args = new + pools
+    kw = {"interpret": True} if impl == "pallas" else {}
+    out, *got = fn(q, *args, bt, lens, pos, layer, **kw)
+    want_out, want = _per_layer_op(q, new, pools, bt, lens, pos, layer)
+    assert jnp.max(jnp.abs(out - want_out)) < 2e-5
+    assert all(jnp.array_equal(g, w) for g, w in zip(got, want))
 
 
 def test_kv_pages_fetched_contract():
@@ -131,6 +227,49 @@ def test_decode_step_paged_matches_dense(arch, kv_quant):
         lp, paged = lm_decode_step(params, cfg, paged,
                                    jnp.asarray(toks[:, i]))
         assert jnp.array_equal(ld, lp), f"divergence at step {i}"
+
+
+@pytest.mark.parametrize("window", [None, 16], ids=["full", "window"])
+@pytest.mark.parametrize("pool_dtype", ["bf16", "f32", "int8"])
+def test_decode_step_writes_one_row_per_lane_per_layer(pool_dtype, window):
+    """One ``lm_decode_step`` over a paged cache changes exactly the new
+    token's (Hkv, D) row -- its (Hkv,) scales for int8 -- of every live
+    lane in every layer of each pool, and leaves every other element of
+    the stacked pools bitwise as it was."""
+    cfg = get_config("qwen2.5-1.5b", smoke=True)
+    cfg = dataclasses.replace(
+        cfg, sliding_window=window,
+        dtype="float32" if pool_dtype == "f32" else cfg.dtype,
+        kv_quant="int8" if pool_dtype == "int8" else None)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    B, max_len, ps = 3, 32, 8
+    cache = init_paged_cache(cfg, B, max_len, page_size=ps)
+    t_w = cache["block_tables"].shape[1]
+    cache["block_tables"] = _shuffled_tables(B, t_w, B * t_w, seed=1)
+    # ragged, and past the ring's capacity for the window lanes
+    cache["len"] = jnp.array([3, 8, 21 if window else 13], jnp.int32)
+    pool_keys = [k for k in cache if k.endswith("_pages")]
+    for i, key in enumerate(pool_keys):        # every old row is nonzero
+        x = jax.random.normal(jax.random.PRNGKey(10 + i), cache[key].shape)
+        cache[key] = (jnp.abs(x) + 0.5 if "scale" in key
+                      else x * 60).astype(cache[key].dtype)
+    old = {k: np.asarray(cache[k]) for k in pool_keys}
+    toks = jnp.asarray([5, 6, 7], jnp.int32)
+    _, new = jax.jit(lambda c: lm_decode_step(params, cfg, c, toks))(cache)
+    slot = np.asarray(cache["len"]) % (t_w * ps)
+    page = np.asarray(cache["block_tables"])[np.arange(B), slot // ps]
+    for key in pool_keys:
+        changed = np.asarray(new[key]) != old[key]
+        rows = np.zeros(changed.shape[:4], bool)    # (L, P, Hkv, ps)
+        rows[:, page, :, slot % ps] = True
+        if changed.ndim == 5:
+            rows = rows[..., None]
+        assert not np.any(changed & ~rows), key     # nothing else moved
+        per_row = changed.reshape(cfg.n_layers, -1, changed.shape[2],
+                                  ps, *changed.shape[4:])
+        for b in range(B):                          # every lane, every layer
+            assert per_row[:, page[b], :, slot[b] % ps].reshape(
+                cfg.n_layers, -1).any(axis=1).all(), (key, b)
 
 
 def test_window_rotation_in_block_table():
@@ -199,6 +338,39 @@ def test_engine_paged_token_exact(small_model, temperature, kv_quant):
     assert dense == paged
     eng.pool.check()
     assert eng.pool.n_in_use == 0          # everything freed at the end
+
+
+@pytest.mark.parametrize("kv_quant", [None, "int8"])
+def test_unwritten_pools_change_the_streams(small_model, monkeypatch,
+                                            kv_quant):
+    """The fault an in-place write can bring: a decode op that hands
+    back the pools it was given, so no decoded key or value reaches
+    them.  The engine still serves, but its greedy streams leave the
+    dense engine's, which the written pools reproduce."""
+    import repro.models.attention as attention
+    cfg, params = small_model
+    if kv_quant:
+        cfg = dataclasses.replace(cfg, kv_quant=kv_quant)
+    prompts = _prompts(cfg, [5, 9, 6, 12, 7], seed=1)
+    kw = dict(n_lanes=2, max_len=32, dispatch_n=4, rng_seed=7)
+    dense, _ = _serve(cfg, params, prompts, 12, **kw)
+    paged, _ = _serve(cfg, params, prompts, 12, paged=True, page_size=8,
+                      **kw)
+    assert paged == dense
+
+    name = ("decode_attention_paged_q8" if kv_quant
+            else "decode_attention_paged")
+    op, n_rows = getattr(attention, name), 5 if kv_quant else 3
+    n_pools = 4 if kv_quant else 2
+
+    def unwritten(*args):
+        out = op(*args)[0]
+        return (out, *args[n_rows:n_rows + n_pools])
+    monkeypatch.setattr(attention, name, unwritten)
+    broken, _ = _serve(cfg, params, prompts, 12, paged=True, page_size=8,
+                       **kw)
+    assert broken != dense
+    assert [len(s) for s in broken] == [len(s) for s in dense]
 
 
 def test_admission_scales_with_bytes_not_lanes(small_model):

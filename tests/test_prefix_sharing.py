@@ -28,6 +28,7 @@ Four layers of invariants:
 """
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -284,6 +285,67 @@ def test_engine_cow_on_divergent_append(small_model):
     assert eng.stats["prefix_tokens_matched"] == 20   # 2 full + partial
     assert eng.stats["prefix_cow_copies"] >= 1        # divergent append
     _flush_and_check_empty(eng)
+
+
+def _undonated(eng):
+    """The engine's decode dispatch, jitted the same way but without
+    donating the per-layer state."""
+    eng._decode_n = jax.jit(
+        functools.partial(eng._decode_n_fn, temperature=eng.temperature,
+                          len_cap=eng.max_len - 1),
+        static_argnames=("n_steps",))
+    return eng
+
+
+def _admit_cow_evict_retire(eng, donor, ext):
+    """An admission, a copy-on-write admission over the donor's partial
+    tail page, an evict/restore of that lane, then every lane retires."""
+    dreq = Request(uid=0, prompt=donor.copy(), max_new_tokens=8)
+    creq = Request(uid=1, prompt=ext.copy(), max_new_tokens=8)
+    assert eng.admit(dreq)
+    eng.decode_n()
+    assert eng.admit(creq)
+    eng.decode_n()
+    lane = next(i for i, r in enumerate(eng.lane_req) if r is creq)
+    ckpt = eng.evict(lane)
+    eng.decode_n()
+    assert eng.restore(ckpt)
+    _drain(eng)
+    assert eng.stats["prefix_cow_copies"] >= 1
+    assert eng.stats["preemptions"] == eng.stats["restores"] == 1
+    return [tuple(dreq.generated), tuple(creq.generated)]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9])
+def test_decode_dispatch_donates_the_cache(small_model, temperature):
+    """The decode dispatch updates the page pools in place: the arrays
+    it was handed are deleted after it, and the engine's streams equal
+    those of the same dispatch without donation, across an admission, a
+    CoW, an evict/restore and the retirements."""
+    cfg, params = small_model
+    head = _head(cfg)
+    donor = _family(cfg, head, [4])[0]           # plen 20: 2 full + 4
+    ext = np.concatenate([donor, np.array([3, 5], dtype=np.int32)])
+    kw = dict(ENGINE_KW, temperature=temperature, prefix_sharing=True)
+
+    eng = ServeEngine(cfg, params, **kw)
+    assert eng.admit(Request(uid=9, prompt=head.copy(), max_new_tokens=2))
+    handed = {k: eng.cache[k] for k in ("k_pages", "v_pages")}
+    eng.decode_n()
+    assert all(x.is_deleted() for x in handed.values())
+    assert not any(x.is_deleted() for x in eng.cache.values())
+    _drain(eng)
+    eng.prefix_cache.flush()
+
+    donated = _admit_cow_evict_retire(ServeEngine(cfg, params, **kw),
+                                      donor, ext)
+    kept = _admit_cow_evict_retire(_undonated(ServeEngine(cfg, params,
+                                                          **kw)),
+                                   donor, ext)
+    assert donated == kept
+    base, _ = _serve(cfg, params, [donor, ext], 8, **dict(
+        ENGINE_KW, temperature=temperature))
+    assert donated == list(base)
 
 
 def test_engine_trims_cache_under_admission_pressure(small_model):
